@@ -24,6 +24,7 @@ from repro.configs import get_smoke_config
 from repro.core import (PilotDescription, PilotManager, Session,
                         TaskDescription, TaskManager)
 from repro.distributed.train_step import make_train_step
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.optim import adamw
 
@@ -34,6 +35,7 @@ def main():
     ap.add_argument("--docking-batch", type=int, default=16)
     ap.add_argument("--train-steps", type=int, default=3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # the "SST surrogate": a reduced transformer trained on the fly
     cfg = get_smoke_config("stablelm-3b", d_model=96, num_layers=2)

@@ -65,6 +65,8 @@ def hybrid_middleware():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sim_experiment()
     tiny_training()
     hybrid_middleware()

@@ -7,6 +7,7 @@ caches over a host mesh; any of the 10 assigned archs via --arch.
 import argparse
 
 from repro.configs import ARCH_IDS, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import serve_batch
 
 
@@ -17,6 +18,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=48)
     ap.add_argument("--max-new-tokens", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch)
     print(f"[serve_lm] {args.arch} (reduced config, "
           f"{cfg.num_params()/1e3:.0f}K params)")

@@ -10,6 +10,7 @@ import argparse
 import dataclasses
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import train
 
 
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config("mamba2-130m")
     if args.d_model != cfg.d_model:

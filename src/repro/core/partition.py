@@ -32,5 +32,6 @@ def carve_submeshes(mesh, n_partitions: int, axis: str = "data"
         slicer = [slice(None)] * mesh.devices.ndim
         slicer[idx] = slice(lo, hi)
         parts.append(MeshPartition(i, Mesh(mesh.devices[tuple(slicer)],
-                                           mesh.axis_names)))
+                                           mesh.axis_names,
+                                           axis_types=mesh.axis_types)))
     return parts

@@ -17,22 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):                        # jax >= 0.6
-    shard_map = jax.shard_map
-else:                                                # jax 0.4.x compat
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None):
-        """Map the modern ``jax.shard_map`` keywords (``axis_names``,
-        ``check_vma``) onto the legacy experimental API (``auto``,
-        ``check_rep``)."""
-        auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-                if axis_names is not None else frozenset())
-        return _legacy_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=bool(check_vma) if check_vma is not None else True,
-            auto=auto)
 
 
 def quantize_int8(x: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
@@ -104,7 +88,7 @@ def make_local_grad_fn(loss_fn: Callable, mesh: Mesh,
             spec[batch_dim_map.get(k, 0)] = dp_axes
             batch_specs[k] = P(*spec)
 
-        @partial(shard_map, mesh=mesh, axis_names=frozenset(dp_axes),
+        @partial(jax.shard_map, mesh=mesh, axis_names=frozenset(dp_axes),
                  in_specs=(param_specs, batch_specs),
                  out_specs=(param_specs, P()), check_vma=False)
         def inner(p, b):

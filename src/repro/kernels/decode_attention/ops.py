@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
+from ..nograd import forward_only
 from .decode_attention import decode_attention_bhd
 
 
@@ -20,8 +21,12 @@ def decode_attention(q, k_cache, v_cache, valid_len, *, scale: float,
     kt = jnp.swapaxes(k_cache, 1, 2)              # (B, KV, S, hd)
     vt = jnp.swapaxes(v_cache, 1, 2)
     if use_pallas:
-        ot = decode_attention_bhd(qt, kt, vt, valid_len, scale=scale,
-                                  block_k=block_k, interpret=interpret)
+        ot = forward_only(
+            "decode_attention",
+            lambda q, k, v, n: decode_attention_bhd(q, k, v, n, scale=scale,
+                                                    block_k=block_k,
+                                                    interpret=interpret),
+            qt, kt, vt, valid_len)
     else:
         ot = ref.decode_attention_ref(qt, kt, vt, valid_len, scale=scale)
     return jnp.swapaxes(ot, 1, 2)

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
+from ..nograd import forward_only
 from .flash_attention import flash_attention_bhsd
 
 
@@ -21,8 +22,12 @@ def flash_attention(q, k, v, *, scale: float, causal: bool = True,
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     if use_pallas:
-        ot = flash_attention_bhsd(qt, kt, vt, scale=scale, causal=causal,
-                                  interpret=interpret)
+        ot = forward_only(
+            "flash_attention",
+            lambda q, k, v: flash_attention_bhsd(q, k, v, scale=scale,
+                                                 causal=causal,
+                                                 interpret=interpret),
+            qt, kt, vt)
     else:
         ot = ref.attention_ref(qt, kt, vt, scale=scale, causal=causal)
     return jnp.swapaxes(ot, 1, 2)

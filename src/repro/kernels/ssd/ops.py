@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
+from ..nograd import forward_only
 
 
 @partial(jax.jit, static_argnames=("chunk", "use_pallas", "interpret",
@@ -23,7 +24,10 @@ def ssd(x, dt, A, Bm, Cm, *, chunk: int = 256, use_pallas: bool = False,
     """SSD scan. See kernels/ssd/ref.py for shapes."""
     if use_pallas:
         from .ssd import ssd_pallas
-        return ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret,
-                          h0=h0)
+        return forward_only(
+            "ssd",
+            lambda *a: ssd_pallas(*a, chunk=chunk, interpret=interpret,
+                                  h0=h0),
+            x, dt, A, Bm, Cm)
     return ref.ssd_chunked(x, dt, A, Bm, Cm, chunk=chunk, h0=h0,
                            precision=precision)

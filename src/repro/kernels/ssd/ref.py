@@ -86,12 +86,16 @@ def ssd_chunked(x, dt, A, Bm, Cm, *, chunk: int = 256,
     # att[i, j] = C_i . B_j * exp(cum_i - cum_j) * dt_j   for j <= i
     cb = jnp.einsum("bclhn,bcshn->bchls", Ch, Bh,
                     preferred_element_type=jnp.float32)  # (B,nc,H,L,L) l=i,s=j
-    decay = jnp.exp(cum[:, :, :, None, :].transpose(0, 1, 4, 2, 3)
-                    - cum.transpose(0, 1, 3, 2)[:, :, :, None, :])
-    # decay[b,c,h,i,j] = exp(cum[b,c,i,h] - cum[b,c,j,h])
+    # decay[b,c,h,i,j] = exp(cum[b,c,i,h] - cum[b,c,j,h]) for j <= i, else 0.
+    # The exponent is masked before exp: above the diagonal it is positive
+    # and overflows to inf at real dt*A over a 256-token chunk, and the
+    # gradient of a where() over inf is 0 * inf = nan
     idx = jnp.arange(L)
     causal = (idx[:, None] >= idx[None, :])
-    att = jnp.where(causal[None, None, None], cb * decay, 0.0)
+    seg = (cum[:, :, :, None, :].transpose(0, 1, 4, 2, 3)
+           - cum.transpose(0, 1, 3, 2)[:, :, :, None, :])
+    decay = jnp.exp(jnp.where(causal[None, None, None], seg, -jnp.inf))
+    att = cb * decay
     att = att * dtf.transpose(0, 1, 3, 2)[:, :, :, None, :]     # * dt_j
     y_intra = jnp.einsum("bchls,bcshp->bclhp", att.astype(mm_dtype), xf,
                          preferred_element_type=jnp.float32)

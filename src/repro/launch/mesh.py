@@ -11,7 +11,7 @@ import math
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -22,13 +22,17 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    """Auto-typed mesh: the model code places arrays with
+    ``with_sharding_constraint`` and leaves the rest to the compiler, which
+    jax's default Explicit axes refuse (e.g. the embedding gather)."""
     n = math.prod(shape)
     devices = jax.devices()
     if len(devices) < n:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, have {len(devices)} "
             "(dry-run must set --xla_force_host_platform_device_count)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices[:n])
 
 
 def make_host_mesh(model_parallel: int = 1) -> Mesh:
@@ -46,4 +50,4 @@ def submesh(mesh: Mesh, axis: str, lo: int, hi: int) -> Mesh:
     slicer = [slice(None)] * devs.ndim
     slicer[idx] = slice(lo, hi)
     sub = devs[tuple(slicer)]
-    return Mesh(sub, mesh.axis_names)
+    return Mesh(sub, mesh.axis_names, axis_types=mesh.axis_types)

@@ -4,6 +4,7 @@ stage in real mode."""
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 from typing import Dict, List, Optional
 
@@ -15,15 +16,23 @@ from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ModelConfig
 from repro.distributed.serve_step import (make_decode_step, make_prefill_step,
                                           pad_cache, sample)
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 
 
-def _positions(cfg: ModelConfig, B: int, S: int, start: int = 0):
+def positions(cfg: ModelConfig, B: int, S: int, start: int = 0):
     base = start + jnp.arange(S, dtype=jnp.int32)
     if cfg.rope_kind == "mrope":
         return jnp.broadcast_to(base[None, None], (3, B, S))
     return jnp.broadcast_to(base[None], (B, S))
+
+
+@functools.lru_cache(maxsize=None)
+def serve_steps(cfg: ModelConfig):
+    """Jitted (prefill, decode) for ``cfg``, built once per config so
+    repeated ``generate()`` calls reuse their compiled programs."""
+    return (jax.jit(make_prefill_step(cfg)),
+            jax.jit(make_decode_step(cfg), donate_argnums=(2,)))
 
 
 def generate(params, cfg: ModelConfig, prompts: jnp.ndarray, *,
@@ -32,10 +41,9 @@ def generate(params, cfg: ModelConfig, prompts: jnp.ndarray, *,
     """prompts (B, S) int32 -> (B, S + max_new_tokens)."""
     B, S = prompts.shape
     key = key if key is not None else jax.random.PRNGKey(0)
-    prefill = jax.jit(make_prefill_step(cfg))
-    decode = jax.jit(make_decode_step(cfg), donate_argnums=(2,))
+    prefill, decode = serve_steps(cfg)
 
-    batch = {"tokens": prompts, "positions": _positions(cfg, B, S)}
+    batch = {"tokens": prompts, "positions": positions(cfg, B, S)}
     logits, cache = prefill(params, batch)
     cache = pad_cache(cache, cfg, S + max_new_tokens)
     tokens = [sample(logits, key, temperature, cfg.vocab_size)]
@@ -43,7 +51,7 @@ def generate(params, cfg: ModelConfig, prompts: jnp.ndarray, *,
     for t in range(max_new_tokens - 1):
         key, sub = jax.random.split(key)
         db = {"tokens": tokens[-1],
-              "positions": _positions(cfg, B, 1, start=S + t)}
+              "positions": positions(cfg, B, 1, start=S + t)}
         logits, cache = decode(params, db, cache)
         tokens.append(sample(logits, sub, temperature, cfg.vocab_size))
     return jnp.concatenate(out + tokens, axis=1)
@@ -78,6 +86,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--max-new-tokens", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))
     serve_batch(cfg, n_requests=args.requests, prompt_len=args.prompt_len,

@@ -25,6 +25,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import DataConfig, PrefetchingLoader, make_loader
 from repro.distributed import sharding as SH
 from repro.distributed.train_step import make_train_step
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.optim import adamw
@@ -41,14 +42,19 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int,
                                                warmup_steps=max(2, steps // 10))
     dp_axes = SH.batch_axes(mesh, cfg, global_batch)
 
-    params = M.init_params(jax.random.PRNGKey(seed), cfg)
-    opt_state = adamw.init(params)
+    # params and optimizer state are made on the mesh's own devices, so a
+    # task on one partition leaves nothing on the process's default device
+    def init_params():
+        return M.init_params(jax.random.PRNGKey(seed), cfg)
+
+    shapes = jax.eval_shape(init_params)
     p_shard = jax.tree.map(lambda s: NamedSharding(mesh, s),
-                           SH.params_pspec(cfg, mesh, params))
-    o_shard = jax.tree.map(lambda s: NamedSharding(mesh, s),
-                           SH.opt_state_pspec(cfg, mesh, opt_state))
-    params = jax.device_put(params, p_shard)
-    opt_state = jax.device_put(opt_state, o_shard)
+                           SH.params_pspec(cfg, mesh, shapes))
+    o_shard = jax.tree.map(
+        lambda s: NamedSharding(mesh, s),
+        SH.opt_state_pspec(cfg, mesh, jax.eval_shape(adamw.init, shapes)))
+    params = jax.jit(init_params, out_shardings=p_shard)()
+    opt_state = jax.jit(adamw.init, out_shardings=o_shard)(params)
 
     start_step = 0
     ckpt = CheckpointManager(ckpt_dir) if ckpt_dir else None
@@ -122,6 +128,7 @@ def main():
     ap.add_argument("--d-model", type=int, default=0,
                     help="override d_model for --smoke scaling")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         overrides = {}

@@ -44,16 +44,30 @@ def attn_weights_core(q, k, *, scale: float, q_offset, kv_valid_len) -> jnp.ndar
 
 def attn_core(q, k, v, *, scale: float, q_offset=0, kv_valid_len=None,
               use_pallas: bool = False) -> jnp.ndarray:
-    """q (B,Sq,H,hd), k/v (B,Sk,KV,hd) -> (B,Sq,H,hd_v)."""
+    """q (B,Sq,H,hd), k/v (B,Sk,KV,hd) -> (B,Sq,H,hd_v).
+
+    ``use_pallas=True`` runs the flash kernel (full causal sequence from
+    position 0) or the decode kernel (one query against a cache); any other
+    case raises rather than silently running the jnp path."""
     B, Sq, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
-    if use_pallas and Sq > 1 and kv_valid_len is None:
-        from repro.kernels.flash_attention import ops as fa_ops
-        return fa_ops.flash_attention(q, k, v, scale=scale, causal=True)
-    if use_pallas and Sq == 1 and kv_valid_len is not None:
-        from repro.kernels.decode_attention import ops as da_ops
-        return da_ops.decode_attention(q, k, v, kv_valid_len, scale=scale)
+    if use_pallas:
+        if v.shape[-1] != hd:
+            raise ValueError(
+                f"use_pallas: no Pallas attention kernel for value width "
+                f"{v.shape[-1]} != query width {hd} (MLA); use_pallas=False")
+        if Sq > 1 and kv_valid_len is None and isinstance(q_offset, int) \
+                and q_offset == 0:
+            from repro.kernels.flash_attention import ops as fa_ops
+            return fa_ops.flash_attention(q, k, v, scale=scale, causal=True)
+        if Sq == 1 and kv_valid_len is not None:
+            from repro.kernels.decode_attention import ops as da_ops
+            return da_ops.decode_attention(q, k, v, kv_valid_len, scale=scale)
+        raise ValueError(
+            f"use_pallas: no Pallas attention kernel for Sq={Sq}, "
+            f"q_offset={q_offset!r}, kv_valid_len={kv_valid_len!r}; "
+            "use_pallas=False")
     qg = q.reshape(B, Sq, KV, G, hd)
     w = attn_weights_core(qg, k, scale=scale, q_offset=q_offset,
                           kv_valid_len=kv_valid_len)

@@ -18,7 +18,9 @@ Backends mirror the simulation split:
   * ``funcpool`` — Raptor/Dragon-style master/worker function execution:
     persistent OS worker processes pull pickled callables off a shared queue
     (no per-call process spawn, true multi-core parallelism); a collector
-    thread commits completions back into the task pipeline.
+    thread commits completions back into the task pipeline. The workers are
+    CPU-only: a chip belongs to one process, and that is the agent's, so
+    chip work stays in-process (``dragon`` threads, ``flux`` partitions).
 
 All task state transitions are committed under ``engine.lock`` and followed
 by ``engine.notify()``, so the agent's single-threaded lifecycle logic
@@ -34,6 +36,7 @@ import subprocess
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
+from contextlib import contextmanager
 from typing import Dict, List, Optional
 
 from repro.core.executors.base import BaseExecutor
@@ -471,6 +474,26 @@ class SubprocessExecutor(RealExecutorBase):
         return proc.stdout
 
 
+_ENV_LOCK = threading.Lock()
+
+
+@contextmanager
+def _cpu_only_children():
+    """Processes started inside inherit ``JAX_PLATFORMS=cpu``: a child never
+    loads the accelerator runtime, so it can neither inherit nor contend for
+    a chip that this process may hold."""
+    with _ENV_LOCK:
+        prev = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            yield
+        finally:
+            if prev is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = prev
+
+
 def _funcpool_worker(task_q, result_q):
     """Persistent worker loop: pull one pickled *batch* of
     (uid, attempt, fn, args, kwargs) jobs per queue op, execute them
@@ -482,8 +505,8 @@ def _funcpool_worker(task_q, result_q):
     Dragon paper reports. Runs until the ``None`` sentinel. Payloads cross
     the queues as explicit pickle blobs so serialization errors surface
     synchronously at the pickling site instead of dying in a queue feeder
-    thread. Lives at module level so it pickles under any multiprocessing
-    start method."""
+    thread. Lives at module level so the ``spawn`` start method can import
+    it."""
     import pickle
 
     while True:
@@ -519,7 +542,7 @@ def _funcpool_worker(task_q, result_q):
 class FuncPoolExecutor(BaseExecutor):
     """Raptor/Dragon-style master/worker function execution over persistent
     OS processes: workers are spawned once at ``start()`` and dispatch
-    happens over shared queues — executing a call never forks, so throughput
+    happens over shared queues — executing a call never spawns, so throughput
     is queue-bound instead of process-spawn-bound (~100/s), which is exactly
     the paper's function-mode speedup. Jobs cross the queue as *batched*
     pickle blobs (one blob per ``batch`` jobs per mp.Queue op) and the
@@ -528,14 +551,18 @@ class FuncPoolExecutor(BaseExecutor):
     one. The collector converts worker completion records into
     task-pipeline transitions (timestamps mapped from the workers'
     CLOCK_MONOTONIC stamps onto the engine clock), committed under
-    ``engine.lock`` like every other real backend."""
+    ``engine.lock`` like every other real backend.
+
+    Workers start with ``spawn``, never ``fork``: a fork would copy a
+    process that may hold the chip. They run with ``JAX_PLATFORMS=cpu``, so
+    payloads that import jax get the CPU backend."""
 
     kind = "funcpool"
     accepts_static = True
 
     def __init__(self, engine, nodes: int = 1, spec=None,
-                 workers: Optional[int] = None, start_method: str = "",
-                 batch: int = 128, name: str = "funcpool", **_):
+                 workers: Optional[int] = None, batch: int = 128,
+                 name: str = "funcpool", **_):
         super().__init__(name)
         self.engine = engine
         self.workers = workers or min(4, os.cpu_count() or 1)
@@ -543,9 +570,7 @@ class FuncPoolExecutor(BaseExecutor):
         # a batch executes on one worker, so very uneven payload durations
         # may warrant a smaller batch to rebalance
         self.batch = max(1, batch)
-        methods = mp.get_all_start_methods()
-        self._ctx = mp.get_context(
-            start_method or ("fork" if "fork" in methods else "spawn"))
+        self._ctx = mp.get_context("spawn")
         self._inflight: Dict[str, Task] = {}
         self._procs: List[mp.Process] = []
         self._task_q = None
@@ -560,12 +585,13 @@ class FuncPoolExecutor(BaseExecutor):
         # collector needs the same lock to drain results would deadlock
         self._task_q = self._ctx.Queue()
         self._result_q = self._ctx.Queue()
-        for _ in range(self.workers):
-            p = self._ctx.Process(target=_funcpool_worker,
-                                  args=(self._task_q, self._result_q),
-                                  daemon=True)
-            p.start()
-            self._procs.append(p)
+        with _cpu_only_children():
+            for _ in range(self.workers):
+                p = self._ctx.Process(target=_funcpool_worker,
+                                      args=(self._task_q, self._result_q),
+                                      daemon=True)
+                p.start()
+                self._procs.append(p)
         self._collector = threading.Thread(target=self._collect,
                                            name=f"{self.name}-collector",
                                            daemon=True)

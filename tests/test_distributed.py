@@ -13,18 +13,9 @@ from repro.distributed import sharding as SH
 from repro.distributed.compression import int8_psum_mean, quantize_int8
 from repro.launch import specs as SP
 
-def _abstract_mesh(**axes):
-    """AbstractMesh across jax versions: 0.4.x takes a tuple of
-    (name, size) pairs; >=0.5 takes (axis_sizes, axis_names)."""
-    try:
-        return AbstractMesh(tuple(axes.items()))
-    except TypeError:
-        return AbstractMesh(tuple(axes.values()), tuple(axes.keys()))
-
-
 MESHES = {
-    "single_pod": _abstract_mesh(data=16, model=16),
-    "multi_pod": _abstract_mesh(pod=2, data=16, model=16),
+    "single_pod": AbstractMesh((16, 16), ("data", "model")),
+    "multi_pod": AbstractMesh((2, 16, 16), ("pod", "data", "model")),
 }
 
 
@@ -132,9 +123,7 @@ def test_int8_psum_mean_single_shard():
     from functools import partial
     x = jnp.asarray(np.random.RandomState(1).randn(64), jnp.float32)
 
-    from repro.distributed.compression import shard_map
-
-    @partial(shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
              check_vma=False)
     def f(v):
         return int8_psum_mean(v, ("data",), 1)

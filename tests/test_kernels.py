@@ -149,6 +149,63 @@ def test_ssd_ops_dispatcher():
     assert float(jnp.max(jnp.abs(y_x - y_p))) < 1e-4
 
 
+def test_ssd_chunked_grad_finite_at_strong_decay():
+    """The XLA path trains mamba2: at a 256-token chunk with dt*A near -1.6
+    per token, the decay above the diagonal overflows unless it is masked
+    before exp, and the gradient turns nan."""
+    B, S, H, G, P, N = 1, 512, 2, 1, 8, 16
+    ks = jax.random.split(KEY, 3)
+    x = jax.random.normal(ks[0], (B, S, H, P), jnp.float32)
+    dt = jnp.full((B, S, H), 0.1, jnp.float32)
+    A = jnp.array([-16.0, -1.0], jnp.float32)
+    Bm = jax.random.normal(ks[1], (B, S, G, N), jnp.float32)
+    Cm = jax.random.normal(ks[2], (B, S, G, N), jnp.float32)
+
+    def loss(x, dt, A):
+        y, h = ssd_ref.ssd_chunked(x, dt, A, Bm, Cm, chunk=256)
+        return jnp.sum(y ** 2) + jnp.sum(h ** 2)
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))(x, dt, A)
+    assert all(bool(jnp.isfinite(g).all()) for g in grads)
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention", "decode_attention",
+                                    "ssd"])
+def test_grad_through_pallas_kernel_names_it(kernel):
+    """No Pallas kernel has a backward pass: jax.grad through one raises an
+    error naming the kernel, not a bare AssertionError from inside JAX."""
+    ks = jax.random.split(KEY, 5)
+    if kernel == "ssd":
+        B, S, H, G, P, N = 1, 64, 2, 1, 8, 16
+        dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+        A = -jnp.exp(jax.random.uniform(ks[2], (H,)))
+        Bm = jax.random.normal(ks[3], (B, S, G, N), jnp.float32)
+        Cm = jax.random.normal(ks[4], (B, S, G, N), jnp.float32)
+
+        def f(x):
+            return ssd(x, dt, A, Bm, Cm, chunk=32, use_pallas=True,
+                       interpret=True)[0].sum()
+        x = jax.random.normal(ks[0], (B, S, H, P), jnp.float32)
+    else:
+        B, S, H, hd = 1, 32, 2, 32
+        k = jax.random.normal(ks[1], (B, S, H, hd), jnp.float32)
+        v = jax.random.normal(ks[2], (B, S, H, hd), jnp.float32)
+        if kernel == "flash_attention":
+            def f(q):
+                return flash_attention(q, k, v, scale=hd ** -0.5,
+                                       use_pallas=True, interpret=True).sum()
+            x = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32)
+        else:
+            def f(q):
+                return decode_attention(q, k, v, S, scale=hd ** -0.5,
+                                        use_pallas=True,
+                                        interpret=True).sum()
+            x = jax.random.normal(ks[0], (B, 1, H, hd), jnp.float32)
+    with pytest.raises(NotImplementedError, match=f"Pallas {kernel} kernel "
+                       "has no backward pass"):
+        jax.grad(f)(x)
+
+
 # -------------------------------------------------------------------- rmsnorm
 @settings(max_examples=10, deadline=None)
 @given(rows=st.integers(1, 70), d=st.sampled_from([32, 128, 256]),

@@ -30,6 +30,12 @@ def _boom(x):
     raise ValueError(f"bad request {x}")
 
 
+def _worker_env(_):
+    import multiprocessing
+    return (os.environ.get("JAX_PLATFORMS"),
+            multiprocessing.get_start_method(allow_none=True))
+
+
 # ------------------------------------------------------------ service tasks
 def test_service_lifecycle_states_sim():
     """Replicas run the persistent lifecycle PROVISIONING -> READY ->
@@ -286,6 +292,22 @@ def test_funcpool_real_no_process_per_call():
         assert os.getpid() not in pids
         assert sorted(t.result[1] for t in tasks) == [i * i
                                                       for i in range(300)]
+
+
+def test_funcpool_workers_spawned_cpu_only(monkeypatch):
+    """Funcpool workers never fork a process that may hold the chip: they
+    are spawned, with JAX_PLATFORMS=cpu whatever the parent's setting, and
+    the parent's environment is left as it was."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with Session(mode="real") as s:
+        pilot = PilotManager(s).submit_pilots(
+            PilotDescription(nodes=1, backends={"funcpool": {"workers": 2}}))
+        assert os.environ["JAX_PLATFORMS"] == "tpu"
+        tmgr = TaskManager(s)
+        tmgr.add_pilots(pilot)
+        tasks = tmgr.submit_functions(_worker_env, range(4))
+        assert tmgr.wait_tasks(timeout=60)
+        assert {t.result for t in tasks} == {("cpu", "spawn")}
 
 
 def test_funcpool_real_failure_and_unpicklable():
