@@ -5,7 +5,7 @@ throughput_scale flux-x8 configuration, whose committed wall time in
 ``BENCH_runtime.json`` is the regression baseline):
 
 * **off** — campaign only, nothing derived after the drain;
-* **on**  — campaign with a LiveSampler attached (trace recording is
+* **on**  — campaign with a gauge-only Watcher attached (trace recording is
   always on), then the full post-hoc stack: RunReport.collect (all
   metric families + lifecycle breakdown + reconstructed timeseries)
   plus a capped Chrome trace export, each stage timed.
@@ -43,8 +43,7 @@ from typing import Dict, List, Optional
 
 from repro.core.pilot import PilotDescription
 from repro.core.task import DescriptionBatch, TaskDescription
-from repro.observability import (LiveSampler, RunReport, Watcher,
-                                 export_chrome_trace)
+from repro.observability import RunReport, Watcher, export_chrome_trace
 from repro.runtime import PilotManager, Session, TaskManager
 
 DEFAULT_SCALES = (10_000, 1_000_000)
@@ -55,7 +54,7 @@ WALL_BAND = 1.10
 
 def run_campaign(n_tasks: int, seed: int, observe: bool) -> Dict:
     """One flux-x8 null campaign (throughput_scale protocol); with
-    ``observe`` a LiveSampler rides the drain and the full post-hoc
+    ``observe`` a gauge-only Watcher rides the drain and the full post-hoc
     stack runs afterwards, every stage timed individually."""
     t0 = time.time()
     with Session(mode="sim", seed=seed) as session:
@@ -75,7 +74,8 @@ def run_campaign(n_tasks: int, seed: int, observe: bool) -> Dict:
         tmgr.submit_tasks(payload)
         sampler = None
         if observe:
-            sampler = LiveSampler(pilot.agent, interval=1.0).start()
+            sampler = Watcher(pilot.agent, interval=1.0,
+                              aggregate=False).start()
         tmgr.wait_tasks()
         campaign_wall = time.time() - t0
         out: Dict = {"config": "flux x8", "n_tasks": n_tasks,
@@ -238,7 +238,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         "benchmark": "observability_overhead",
         "protocol": ("two passes per scale over the seeded throughput_scale "
                      "flux-x8 null campaign: campaign-only wall vs campaign "
-                     "with LiveSampler + RunReport.collect + capped Chrome "
+                     "with a gauge-only Watcher + RunReport.collect + capped Chrome "
                      "export; the observed campaign wall is gated to 110% "
                      "of the committed BENCH_runtime wall, post-hoc "
                      "analysis gated to <2s at 1M; --stream adds a third "

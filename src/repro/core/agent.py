@@ -278,45 +278,46 @@ class Agent:
         cohort planner (a batch is an explicit bulk, like ``submit_wave``);
         lists only at ``cohort_min`` size. ``cohort=False`` forces the
         object path for this call."""
-        use_cohort = self._cohort if cohort is None else (self._cohort
-                                                          and cohort)
-        if isinstance(descriptions, DescriptionBatch):
-            if use_cohort:
-                with self.engine.lock:
-                    wave = _cohort.try_plan_batch(self, descriptions)
+        with self.engine.span("rp:submit"):
+            use_cohort = self._cohort if cohort is None else (self._cohort
+                                                              and cohort)
+            engine = self.engine
+            if isinstance(descriptions, DescriptionBatch):
+                if use_cohort:
+                    with engine.lock:
+                        wave = _cohort.try_plan_batch(self, descriptions)
+                    if wave is not None:
+                        return wave
+                return self._submit_batch_objects(descriptions)
+            if use_cohort and len(descriptions) >= self._cohort_min:
+                with engine.lock:
+                    wave = _cohort.try_plan(self, descriptions)
                 if wave is not None:
                     return wave
-            return self._submit_batch_objects(descriptions)
-        if use_cohort and len(descriptions) >= self._cohort_min:
-            with self.engine.lock:
-                wave = _cohort.try_plan(self, descriptions)
-            if wave is not None:
-                return wave
-        out = []
-        engine = self.engine
-        with engine.lock:
-            # pause cyclic GC for the bulk ingestion storm: allocating n
-            # tasks otherwise triggers O(n/threshold) generational
-            # collections, each rescanning the growing live set
-            gc_was_enabled = gc.isenabled()
-            if gc_was_enabled:
-                gc.disable()
-            try:
-                now = engine.now
-                profiler = engine.profiler
-                tasks = self.tasks
-                append = self._dispatch_q.append
-                for d in descriptions:
-                    task = Task(d)
-                    tasks[task.uid] = task
-                    task.advance(TaskState.SCHEDULING, now(), profiler)
-                    append(task)
-                    out.append(task)
-                self._pump_dispatch()
-            finally:
+            out = []
+            with engine.lock:
+                # pause cyclic GC for the bulk ingestion storm: allocating n
+                # tasks otherwise triggers O(n/threshold) generational
+                # collections, each rescanning the growing live set
+                gc_was_enabled = gc.isenabled()
                 if gc_was_enabled:
-                    gc.enable()
-        return out
+                    gc.disable()
+                try:
+                    now = engine.now
+                    profiler = engine.profiler
+                    tasks = self.tasks
+                    append = self._dispatch_q.append
+                    for d in descriptions:
+                        task = Task(d)
+                        tasks[task.uid] = task
+                        task.advance(TaskState.SCHEDULING, now(), profiler)
+                        append(task)
+                        out.append(task)
+                    self._pump_dispatch()
+                finally:
+                    if gc_was_enabled:
+                        gc.enable()
+            return out
 
     def _submit_batch_objects(self, batch: DescriptionBatch) -> List[Task]:
         """Object-path ingestion of a batch: one ``Task`` per row over a
@@ -442,73 +443,82 @@ class Agent:
         # charged batch x interval, holding the RP rate while spending
         # O(1/batch) scheduler events per task
         budget = min(self.dispatch_batch, len(self._dispatch_q))
-        self.engine.schedule(self.dispatch_interval * budget,
-                             self._dispatch_tick, budget)
-
-    def _dispatch_tick(self, budget: int):
-        self._dispatch_busy = False
-        dispatched = 0
-        q = self._dispatch_q
+        delay = self.dispatch_interval * budget
         engine = self.engine
-        profiler = engine.profiler
-        backends = self.backends
-        policy_route = self.policy.route
-        route_cache = self._route_cache
-        speculation = self.speculation
-        # route the whole batch first, then hand each backend its bulk in
-        # one submit_many (RP's bulk path); no sim events can fire between
-        # the two passes, so this is equivalent to interleaved submission
-        groups: Dict[str, List[Task]] = {}
-        held = False
-        while q and dispatched < budget:
-            task = q.popleft()
-            dispatched += 1
-            if task.state is TaskState.CANCELED:
-                continue
-            if route_cache is not None:
-                d = task.description
-                # key covers every description field the static rule chain
-                # and the built-in accepts() predicates read
-                key = (d.backend, d.kind, bool(d.executable), d.cores,
-                       d.gpus, d.nodes, d.coupling, d.fn is not None)
-                name = route_cache.get(key)
-                if name is None:
-                    name = route_cache[key] = policy_route(task, backends)
-            else:
-                name = policy_route(task, backends)
-            ex = backends[name]
-            now = engine.now()
-            wait = getattr(ex, "ready_at", 0.0) - now
-            if wait > 0:
-                # backend still bootstrapping: hold and retry at readiness
-                q.appendleft(task)
-                engine.schedule(wait, self._pump_dispatch)
-                held = True
-                break
-            task.advance(TaskState.QUEUED, now, profiler)
-            grp = groups.get(name)
-            if grp is None:
-                groups[name] = [task]
-            else:
-                grp.append(task)
-        for name, bulk in groups.items():
-            backends[name].submit_many(bulk)
-            if speculation:
-                for task in bulk:
-                    if (task.speculative_of is not None       # no chains
-                            or task.description.kind == "service"):
-                        continue
-                    if task.description.duration > 0:
-                        self._arm_speculation(task)
-                    else:
-                        # duration-free: deadline from the trace quantile
-                        deadline = self._quantile_deadline()
-                        if deadline is not None:
-                            self._arm_speculation(task, deadline)
+        engine.schedule(delay, self._dispatch_tick, budget,
+                        engine.now() + delay)
+
+    def _dispatch_tick(self, budget: int, due: float):
+        """Dispatch up to ``budget`` queued tasks; ``due`` is when this tick
+        was scheduled to fire. On the real engine each task it queues
+        carries ``due`` as its ``tick_due`` stamp."""
+        with self.engine.span("rp:dispatch"):
+            self._dispatch_busy = False
+            dispatched = 0
+            q = self._dispatch_q
+            engine = self.engine
+            profiler = engine.profiler
+            backends = self.backends
+            policy_route = self.policy.route
+            route_cache = self._route_cache
+            speculation = self.speculation
+            # route the whole batch first, then hand each backend its bulk in
+            # one submit_many (RP's bulk path); no sim events can fire between
+            # the two passes, so this is equivalent to interleaved submission
+            groups: Dict[str, List[Task]] = {}
+            held = False
+            stamp_due = engine.mode == "real"
+            while q and dispatched < budget:
+                task = q.popleft()
+                dispatched += 1
+                if task.state is TaskState.CANCELED:
+                    continue
+                if route_cache is not None:
+                    d = task.description
+                    # key covers every description field the static rule chain
+                    # and the built-in accepts() predicates read
+                    key = (d.backend, d.kind, bool(d.executable), d.cores,
+                           d.gpus, d.nodes, d.coupling, d.fn is not None)
+                    name = route_cache.get(key)
+                    if name is None:
+                        name = route_cache[key] = policy_route(task, backends)
+                else:
+                    name = policy_route(task, backends)
+                ex = backends[name]
+                now = engine.now()
+                wait = getattr(ex, "ready_at", 0.0) - now
+                if wait > 0:
+                    # backend still bootstrapping: hold and retry at readiness
+                    q.appendleft(task)
+                    engine.schedule(wait, self._pump_dispatch)
+                    held = True
+                    break
+                task.advance(TaskState.QUEUED, now, profiler)
+                if stamp_due:
+                    task.timestamps["tick_due"] = due
+                grp = groups.get(name)
+                if grp is None:
+                    groups[name] = [task]
+                else:
+                    grp.append(task)
+            for name, bulk in groups.items():
+                backends[name].submit_many(bulk)
+                if speculation:
+                    for task in bulk:
+                        if (task.speculative_of is not None       # no chains
+                                or task.description.kind == "service"):
+                            continue
+                        if task.description.duration > 0:
+                            self._arm_speculation(task)
                         else:
-                            self._spec_pending[task.uid] = task
-        if not held:
-            self._pump_dispatch()
+                            # duration-free: deadline from the trace quantile
+                            deadline = self._quantile_deadline()
+                            if deadline is not None:
+                                self._arm_speculation(task, deadline)
+                            else:
+                                self._spec_pending[task.uid] = task
+            if not held:
+                self._pump_dispatch()
 
     # ------------------------------------------------------------- lifecycle
     def _task_completed(self, task: Task):

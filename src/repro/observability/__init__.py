@@ -19,7 +19,7 @@ from repro.observability.timeseries import (METRICS, Series,
                                             service_queue_depth, throughput,
                                             timeseries)
 from repro.observability.stream import (ALERT_EVENT, Alert, HealthMonitor,
-                                        HealthRule, LiveSampler,
+                                        HealthRule,
                                         QueueRunawayRule, ServiceLatencyRule,
                                         StallRule, StreamingBreakdown,
                                         StreamingLevel, StreamingThroughput,
@@ -35,7 +35,7 @@ __all__ = [
     "METRICS", "Series", "timeseries", "throughput", "inflight", "occupancy",
     "backend_inflight", "sched_hold_depth", "service_queue_depth",
     "ALERT_EVENT", "TraceCursor", "StreamingThroughput", "StreamingLevel",
-    "StreamingBreakdown", "Watcher", "LiveSampler", "render_frame",
+    "StreamingBreakdown", "Watcher", "render_frame",
     "Alert", "HealthRule", "HealthMonitor", "StallRule",
     "ThroughputDropRule", "QueueRunawayRule", "ServiceLatencyRule",
     "chrome_trace", "export_chrome_trace",

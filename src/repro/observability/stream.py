@@ -25,8 +25,7 @@ Architecture — three layers, each usable alone:
   summation order (<1e-9 relative at a million tasks);
   ``StreamingBreakdown.stats(exact_quantiles=True)`` even reproduces the
   post-hoc percentiles exactly with one O(n) gather at drain.
-* :class:`Watcher` — the engine-driven orchestrator (absorbing the old
-  ``LiveSampler``, still exported for compatibility): one scheduled
+* :class:`Watcher` — the engine-driven orchestrator: one scheduled
   callback per ``interval`` folds the delta, samples the instantaneous
   gauges the trace cannot reconstruct (executor queue depth, free cores),
   evaluates the health rules, and optionally appends a JSONL metric
@@ -689,7 +688,7 @@ class HealthMonitor:
 
 
 # ---------------------------------------------------------------------------
-# watcher (the orchestrator; absorbs the old LiveSampler)
+# watcher (the orchestrator)
 # ---------------------------------------------------------------------------
 
 @dataclass
@@ -712,7 +711,7 @@ class Watcher:
 
     Parameters beyond the obvious: ``dt`` is the aggregation bin width
     (defaults to ``interval``); ``aggregate=False`` keeps only the gauge
-    samples (the old LiveSampler behavior, near-zero cost);
+    samples (near-zero cost);
     ``emit`` appends one JSON line per tick (final line carries
     ``"final": true``); ``promfile`` atomically rewrites an
     OpenMetrics-style text exposition each tick; ``on_tick(watcher)``
@@ -1076,7 +1075,7 @@ class Watcher:
         return lvl.series(divisor=float(total), name="occupancy")
 
     def series(self, field_name: str = "n_unfinished") -> Series:
-        """Gauge samples as a Series (LiveSampler-compatible)."""
+        """Gauge samples as a Series."""
         t = np.asarray([s.t for s in self.samples])
         v = np.asarray([getattr(s, field_name) for s in self.samples],
                        dtype=np.float64)
@@ -1158,15 +1157,6 @@ class Watcher:
         with open(tmp, "w") as fh:
             fh.write(self.openmetrics())
         os.replace(tmp, self.promfile)
-
-
-class LiveSampler(Watcher):
-    """Back-compat shim: the PR 8 gauge-only sampler is now a Watcher
-    with aggregation off (one cursor poll per tick to keep the stall
-    bookkeeping honest, no series folding)."""
-
-    def __init__(self, agent, interval: float = 1.0):
-        super().__init__(agent, interval=interval, aggregate=False)
 
 
 # ---------------------------------------------------------------------------

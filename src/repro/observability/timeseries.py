@@ -14,7 +14,7 @@ events incrementally, delta by delta — land on bit-identical bin edges
 and (for the integer-weighted counts and levels here) bit-identical
 values.  Live sampling of instantaneous gauges (executor queue depth,
 free cores) lives in :mod:`repro.observability.stream` too
-(:class:`~repro.observability.stream.LiveSampler`).
+(:class:`~repro.observability.stream.Watcher`).
 """
 from __future__ import annotations
 

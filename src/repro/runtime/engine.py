@@ -10,19 +10,23 @@ campaigns) runs on either implementation:
   4-1024 node allocations, deterministic).
 * :class:`RealEngine` — wall clock + timer threads; payloads actually execute
   on this host. All runtime callbacks are serialized under ``engine.lock`` so
-  the single-threaded agent logic holds unchanged.
+  the single-threaded agent logic holds unchanged. Its host path opens
+  ``rp:*`` spans (:meth:`Engine.span`) in the ``jax.profiler`` trace.
 
 This mirrors RADICAL-Pilot's layering (arXiv:2103.00091): one task-management
 pipeline over interchangeable runtime backends.
 """
 from __future__ import annotations
 
+import _thread
 import gc
 import math
 import random
+import sys
 import threading
 import time
 from abc import ABC, abstractmethod
+from contextlib import nullcontext
 from typing import Callable, Optional
 
 import numpy as np
@@ -30,6 +34,24 @@ import numpy as np
 from repro.core import calibration as CAL
 from repro.core.events import Profiler
 from repro.core.simclock import RealClock, VirtualClock
+
+_NO_SPAN = nullcontext()
+
+
+class _TracedLock(_thread.RLock):
+    """The real engine's ``lock``: an ``RLock`` whose wait, when another
+    thread holds it, is an ``rp:lock:wait`` span of ``engine``. An
+    uncontended acquisition opens no span; the release is the
+    ``RLock``'s own."""
+
+    def __init__(self, engine):
+        self.engine = engine
+
+    def __enter__(self):
+        if not self.acquire(blocking=False):
+            with self.engine.span("rp:lock:wait"):
+                self.acquire()
+        return True
 
 
 class Engine(ABC):
@@ -57,6 +79,11 @@ class Engine(ABC):
         self.duration_fn: Optional[Callable] = None
         # serializes all runtime callbacks; uncontended (same-thread) in sim
         self.lock = threading.RLock()
+
+    def span(self, name: str):
+        """A context that marks ``name`` (``rp:*``) as a host span in the
+        profiler's trace; the sim engine's spans mark nothing."""
+        return _NO_SPAN
 
     # ------------------------------------------------------------------ time
     def now(self) -> float:
@@ -189,12 +216,25 @@ class RealEngine(Engine):
                  srun_cap: int = CAL.SRUN_CONCURRENCY_CAP):
         super().__init__(seed, srun_cap)
         self.clock = RealClock()
+        self.lock = _TracedLock(self)       # every wait for it is traced
         self._cond = threading.Condition(self.lock)
         self._callback_error: Optional[BaseException] = None
 
+    def span(self, name: str):
+        """``jax.profiler.TraceAnnotation(name)``: a host span on the device
+        trace's clock, recorded only while a profile is being taken. A
+        process that has not imported JAX gets a no-op."""
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return _NO_SPAN
+        # JAX stays imported: later spans construct the annotation directly,
+        # with no Python frame of their own on the task's host path
+        self.span = profiler.TraceAnnotation
+        return self.span(name)
+
     def schedule(self, delay: float, fn: Callable, *args):
         def fire():
-            with self._cond:
+            with self.lock:
                 try:
                     fn(*args)
                 except BaseException as e:      # noqa: BLE001
@@ -207,7 +247,7 @@ class RealEngine(Engine):
         return self.clock.schedule(delay, fire)
 
     def notify(self):
-        with self._cond:
+        with self.lock:
             self._cond.notify_all()
 
     def _check_error(self):
@@ -219,7 +259,7 @@ class RealEngine(Engine):
               timeout: Optional[float] = None,
               max_events: int = 50_000_000) -> bool:
         deadline = None if timeout is None else time.monotonic() + timeout
-        with self._cond:
+        with self.lock:
             self._check_error()
             if predicate is None:
                 return True

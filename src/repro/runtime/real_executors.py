@@ -25,6 +25,14 @@ Backends mirror the simulation split:
 All task state transitions are committed under ``engine.lock`` and followed
 by ``engine.notify()``, so the agent's single-threaded lifecycle logic
 (retries, speculation, campaign stage release) runs unchanged on top.
+
+A thread-pool task's host path is traced as ``rp:exec:start`` (the RUNNING
+commit), ``rp:exec:payload``, ``rp:exec:device_wait`` (the payload's device
+arrays finishing) and ``rp:exec:commit`` (the DONE/FAILED commit and its
+``notify``), with ``rp:lock:wait`` wherever the worker waits for the lock.
+Beside the state stamps each task carries ``picked`` (a worker took it),
+``returned`` (the payload returned) and ``ready`` (its device arrays are
+done): exec ends at device completion.
 """
 from __future__ import annotations
 
@@ -33,6 +41,7 @@ import multiprocessing as mp
 import os
 import queue
 import subprocess
+import sys
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -53,6 +62,21 @@ def _accepts_kw(fn, name: str) -> bool:
         return name in inspect.signature(fn).parameters
     except (TypeError, ValueError):
         return False
+
+
+def _device_ready(result):
+    """Wait until the device arrays in ``result`` are computed (JAX
+    dispatch is asynchronous); other leaves pass through. A process that
+    has not imported JAX has no device arrays to wait for. A result JAX
+    cannot flatten (a dict whose keys do not sort) raises, and the task
+    fails: it cannot be known to be computed."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+    arrays = [x for x in jax.tree_util.tree_leaves(result)
+              if isinstance(x, jax.Array)]
+    if arrays:
+        jax.block_until_ready(arrays)
 
 
 class RealExecutorBase(BaseExecutor):
@@ -100,7 +124,10 @@ class RealExecutorBase(BaseExecutor):
         if task.description.kind == "service":
             return self._run_service(task)
         eng = self.engine
-        with eng.lock:
+        clock_now = eng.clock.now       # eng.now() without its extra frame
+        stamps = task.timestamps
+        stamps["picked"] = clock_now()
+        with eng.span("rp:exec:start"), eng.lock:
             self._futures.pop(task.uid, None)
             self._pending_tasks.pop(task.uid, None)
             if task.done:                         # canceled while queued
@@ -116,33 +143,42 @@ class RealExecutorBase(BaseExecutor):
             if wt > 0.0:
                 eng.schedule(wt, self._enforce_walltime, task, attempt)
         try:
-            result = self._payload(task)
+            with eng.span("rp:exec:payload"):
+                result = self._payload(task)
+            stamps["returned"] = clock_now()
+            with eng.span("rp:exec:device_wait"):
+                _device_ready(result)
+            stamps["ready"] = clock_now()
         except Exception as e:                                # noqa: BLE001
             err = f"{type(e).__name__}: {e}"
+            with eng.span("rp:exec:commit"):
+                with eng.lock:
+                    self._active -= 1
+                    # the attempt guard discards a stale thread's commit:
+                    # the task may have been failed by chaos/walltime,
+                    # requeued, and relaunched as a newer attempt while
+                    # this payload ran
+                    if not task.done and task.attempt == attempt:
+                        self._running_tasks.pop(task.uid, None)
+                        task.error = err
+                        task.advance(TaskState.FAILED, eng.now(),
+                                     eng.profiler)
+                        self.stats["failed"] += 1
+                        if self.on_failure:
+                            self.on_failure(task, err)
+                eng.notify()
+            return
+        with eng.span("rp:exec:commit"):
             with eng.lock:
                 self._active -= 1
-                # the attempt guard discards a stale thread's commit: the
-                # task may have been failed by chaos/walltime, requeued,
-                # and relaunched as a newer attempt while this payload ran
                 if not task.done and task.attempt == attempt:
                     self._running_tasks.pop(task.uid, None)
-                    task.error = err
-                    task.advance(TaskState.FAILED, eng.now(), eng.profiler)
-                    self.stats["failed"] += 1
-                    if self.on_failure:
-                        self.on_failure(task, err)
+                    task.result = result
+                    task.advance(TaskState.DONE, eng.now(), eng.profiler)
+                    self.stats["completed"] += 1
+                    if self.on_complete:
+                        self.on_complete(task)
             eng.notify()
-            return
-        with eng.lock:
-            self._active -= 1
-            if not task.done and task.attempt == attempt:
-                self._running_tasks.pop(task.uid, None)
-                task.result = result
-                task.advance(TaskState.DONE, eng.now(), eng.profiler)
-                self.stats["completed"] += 1
-                if self.on_complete:
-                    self.on_complete(task)
-        eng.notify()
 
     def _enforce_walltime(self, task: Task, attempt: int):
         """Walltime timer fired: if that attempt is still running, fail the

@@ -2,8 +2,8 @@
 reference loop (golden), lifecycle decomposition telescoping + reconciliation
 with compute_metrics on both engines and both task paths (object vs cohort
 wave), reconstructed timeseries invariants, Chrome trace export round-trip
-(schema + per-track monotonicity + non-silent slice cap), the LiveSampler
-drain guarantee, and the unified RunReport payload/render/CLI surface."""
+(schema + per-track monotonicity + non-silent slice cap), the gauge-only
+Watcher's drain guarantee, and the unified RunReport payload/render/CLI surface."""
 import json
 
 import numpy as np
@@ -13,7 +13,7 @@ from repro.core import analytics as A
 from repro.core.events import _NAME_MASK, Profiler
 from repro.core.pilot import PilotDescription
 from repro.core.task import STATE_EVENTS, TaskDescription, TaskState
-from repro.observability import (LiveSampler, PHASES, RunReport,
+from repro.observability import (PHASES, RunReport, Watcher,
                                  backend_inflight, chrome_trace,
                                  export_chrome_trace, inflight,
                                  lifecycle_breakdown, occupancy,
@@ -298,7 +298,8 @@ def test_live_sampler_autostops_on_sim_engine():
                              backends={"flux": {"partitions": 2}}))
         tm = TaskManager(session)
         tm.add_pilots(pilot)
-        sampler = LiveSampler(pilot.agent, interval=0.5).start()
+        sampler = Watcher(pilot.agent, interval=0.5,
+                          aggregate=False).start()
         tm.submit_tasks([TaskDescription(cores=1, duration=2.0)
                          for _ in range(40)])
         assert tm.wait_tasks(timeout=60)
