@@ -68,11 +68,13 @@ def causal_conv_step(x_t: jnp.ndarray, w: jnp.ndarray, cache: jnp.ndarray
     return y, window[:, 1:, :]
 
 
-def _ssd_dispatch(cfg: ModelConfig, x4, dt, A, B4, C4, h0=None):
+def _ssd_dispatch(cfg: ModelConfig, x4, dt, A, B4, C4, h0=None,
+                  return_state: bool = True):
     from repro.kernels.ssd import ops as ssd_ops
     return ssd_ops.ssd(x4, dt, A, B4, C4, chunk=cfg.ssm_chunk,
                        use_pallas=cfg.use_pallas, h0=h0,
-                       precision=cfg.ssd_precision)
+                       precision=cfg.ssd_precision,
+                       return_state=return_state)
 
 
 def mamba2_full(p, x, cfg: ModelConfig, *, return_cache: bool = False):
@@ -97,7 +99,8 @@ def mamba2_full(p, x, cfg: ModelConfig, *, return_cache: bool = False):
     C4 = Cc.reshape(B, S, G, N)
     A = -jnp.exp(p["A_log"])
 
-    y4, h_final = _ssd_dispatch(cfg, x4, dt, A, B4, C4)
+    y4, h_final = _ssd_dispatch(cfg, x4, dt, A, B4, C4,
+                                return_state=return_cache)
     y4 = y4 + (p["D"][None, None, :, None] * x4.astype(jnp.float32)).astype(y4.dtype)
 
     y = y4.reshape(B, S, di)

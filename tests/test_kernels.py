@@ -17,7 +17,7 @@ from repro.kernels.fused_rmsnorm import ref as rn_ref
 from repro.kernels.fused_rmsnorm.ops import rmsnorm
 from repro.kernels.ssd import ref as ssd_ref
 from repro.kernels.ssd.ops import ssd
-from repro.kernels.ssd.ssd import ssd_pallas
+from repro.kernels.ssd.ssd import head_block, ssd_pallas
 
 KEY = jax.random.PRNGKey(7)
 
@@ -75,6 +75,9 @@ def test_decode_attention_vs_oracle(shape, valid, dtype):
     ((2, 128, 4, 1, 32, 64), 32),
     ((1, 96, 4, 2, 16, 32), 32),       # grouped B/C, ragged chunks
     ((1, 256, 2, 1, 64, 128), 128),    # production-like tile
+    ((1, 256, 24, 1, 64, 128), 256),   # mamba2-130m heads, all in blocks
+    ((1, 512, 8, 2, 16, 32), 128),     # grouped, state carried per block
+    ((1, 256, 6, 2, 512, 512), 128),   # 3 heads a group, blocks of 1 head
 ])
 def test_ssd_pallas_vs_naive(shape, chunk, dtype):
     B, S, H, G, P, N = shape
@@ -93,6 +96,53 @@ def test_ssd_pallas_vs_naive(shape, chunk, dtype):
           / (float(jnp.max(jnp.abs(h0))) + 1e-9))
     assert max(ry, rh) < (1e-5 if dtype == jnp.float32 else 3e-2), \
         f"{shape}: y={ry:.2e} h={rh:.2e}"
+
+
+@pytest.mark.parametrize("shape,x_bytes,want", [
+    ((24, 1, 64, 128, 256), 2, 24),    # mamba2-130m: one step a layer
+    ((112, 1, 64, 64, 256), 2, 28),    # zamba2-7b: 4 steps, 1792 lanes
+    ((6, 2, 512, 512, 128), 4, 1),     # VMEM forces blocks under a group
+    ((4, 2, 16, 32, 32), 4, 2),        # no tileable block: the whole group
+])
+def test_ssd_head_block(shape, x_bytes, want):
+    """The head block divides the group, never straddles one, and spans
+    whole 128-lane tiles where the shapes allow it."""
+    H, G, P, N, L = shape
+    hb = head_block(H, G, P, N, L, x_bytes, x_bytes)
+    assert hb == want
+    assert (H // G) % hb == 0
+
+
+def _ssd_inputs(B, S, H, G, P, N, dtype=jnp.float32):
+    ks = jax.random.split(KEY, 5)
+    x = jax.random.normal(ks[0], (B, S, H, P), dtype)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    A = -jnp.exp(jax.random.uniform(ks[2], (H,), minval=0.0, maxval=2.0))
+    Bm = jax.random.normal(ks[3], (B, S, G, N), dtype)
+    Cm = jax.random.normal(ks[4], (B, S, G, N), dtype)
+    return x, dt, A, Bm, Cm
+
+
+@pytest.mark.parametrize("shape,chunk", [
+    ((1, 256, 24, 1, 64, 128), 128),   # mamba2-130m heads, two chunks
+    ((2, 96, 4, 2, 16, 32), 32),       # grouped, ragged
+])
+def test_ssd_ops_final_state_only_when_asked(shape, chunk):
+    """The prefill path asks for the final state and gets the XLA path's;
+    the scoring forward does not, and its output is the same."""
+    args = _ssd_inputs(*shape)
+    y_x, h_x = ssd(*args, chunk=chunk, use_pallas=False)
+    y_p, h_p = ssd(*args, chunk=chunk, use_pallas=True, interpret=True,
+                   return_state=True)
+    y_n, h_n = ssd(*args, chunk=chunk, use_pallas=True, interpret=True,
+                   return_state=False)
+    assert h_n is None
+    assert ssd(*args, chunk=chunk, use_pallas=False,
+               return_state=False)[1] is None
+    np.testing.assert_array_equal(np.asarray(y_n), np.asarray(y_p))
+    for got, want in ((h_p, h_x), (y_p, y_x)):
+        assert (float(jnp.max(jnp.abs(got - want)))
+                / (float(jnp.max(jnp.abs(want))) + 1e-9)) < 1e-5
 
 
 @settings(max_examples=8, deadline=None)

@@ -1,7 +1,9 @@
 """Compile each Pallas kernel for a described TPU v5e chip at the widths the
 chip smoke runs (stablelm-3b attention, mamba2-130m SSD, stablelm-3b
-rmsnorm). Nothing runs: the chip's compiler refuses here, at no chip time,
-what interpret mode cannot see (unaligned blocks, unlowerable primitives).
+rmsnorm), and the SSD kernel also as the stream benchmark's scoring forward
+calls it and at zamba2-7b's heads. Nothing runs: the chip's compiler
+refuses here, at no chip time, what interpret mode cannot see (unaligned
+blocks, unlowerable primitives, blocks that overflow VMEM).
 
 The topology is described only inside the module fixture: one process at a
 time may load the TPU library, so it must never happen at import time."""
@@ -71,6 +73,22 @@ def test_ssd_compiles_for_v5e(one_chip):
         lambda x, dt, a, b, c: ssd_pallas(x, dt, a, b, c, chunk=CHUNK),
         one_chip, ((B, S, SSD_H, SSD_P), bf), ((B, S, SSD_H), f32),
         ((SSD_H,), f32), ((B, S, 1, SSD_N), bf), ((B, S, 1, SSD_N), bf))
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("b,s,h,n,return_state", [
+    (1, 256, SSD_H, SSD_N, False),   # a 1 x 256 scoring task, no state
+    (1, 2048, 112, 64, True),        # zamba2-7b: 112 heads of state 64
+])
+def test_ssd_head_blocks_compile_for_v5e(one_chip, b, s, h, n, return_state):
+    """A head block whose blocks and temporaries overflow VMEM, or whose
+    lanes the chip cannot tile, is refused here."""
+    bf, f32 = jnp.bfloat16, jnp.float32
+    txt = _compile_text(
+        lambda x, dt, a, bm, cm: ssd_pallas(x, dt, a, bm, cm, chunk=CHUNK,
+                                            return_state=return_state),
+        one_chip, ((b, s, h, SSD_P), bf), ((b, s, h), f32), ((h,), f32),
+        ((b, s, 1, n), bf), ((b, s, 1, n), bf))
     assert "tpu_custom_call" in txt
 
 
