@@ -26,7 +26,7 @@ class ModelConfig:
     head_dim: int = 0
     d_ff: int = 0
     # --- activations / norms --------------------------------------------------
-    act: str = "silu"                # silu | gelu  (gated: SwiGLU / GeGLU)
+    act: str = "silu"                # silu | gelu (tanh form) | gelu_exact (erf form)
     gated_mlp: bool = True           # False: plain 2-matrix MLP (musicgen)
     qkv_bias: bool = False           # qwen2-vl uses QKV biases
     norm_eps: float = 1e-5
@@ -60,8 +60,11 @@ class ModelConfig:
     ssm_chunk: int = 256
     ssm_groups: int = 1
     ssd_precision: str = "highest"   # "mixed": bf16 SSD matmuls (perf knob)
+    ssm_conv_bias: bool = False      # bias on the causal convolutions (zamba2)
     # --- hybrid (zamba2) ----------------------------------------------------------
-    attn_every: int = 0              # shared attn+mlp block applied every N ssm layers
+    hybrid_layer_ids: Tuple[int, ...] = ()   # mamba2 layers fed a shared block
+    num_mem_blocks: int = 0          # shared attention+MLP blocks, used in turn
+    adapter_rank: int = 0            # per-invocation LoRA on the shared MLP
     # --- frontend -------------------------------------------------------------------
     input_mode: str = "tokens"       # tokens | embeddings (audio / vlm stubs)
     # --- numerics / impl ---------------------------------------------------------------
@@ -72,6 +75,11 @@ class ModelConfig:
     vocab_tp: bool = True            # shard embed/unembed over model axis
     remat: str = "full"              # none | full | dots  (activation ckpt policy)
     scan_layers: bool = True
+
+    def __post_init__(self):
+        # a list (as a JSON file gives it) would make the config unhashable
+        object.__setattr__(self, "hybrid_layer_ids",
+                           tuple(self.hybrid_layer_ids))
 
     # ------------------------------------------------------------------------
     @property
@@ -132,6 +140,7 @@ class ModelConfig:
             conv_dim = di + 2 * g * ns
             per_ssm = (d * (2 * di + 2 * g * ns + self.ssm_heads)  # in_proj
                        + conv_dim * self.ssm_conv                  # conv1d
+                       + conv_dim * self.ssm_conv_bias             # its bias
                        + 3 * self.ssm_heads                        # A, D, dt_bias
                        + di                                        # gated norm
                        + di * d)                                   # out_proj
@@ -139,8 +148,16 @@ class ModelConfig:
             n += self.num_layers * (per_ssm + d)    # + input norm
         elif self.family == "hybrid":
             n += self.num_layers * (per_ssm + d)
-            n_shared = 1
-            n += n_shared * (per_attn + mlp(self.d_ff) + 2 * d)
+            # shared blocks read concat(h, x0): q, k, v are 2d wide, o and
+            # the MLP d wide; two norms (2d and d)
+            hd_all = self.num_heads * self.head_dim
+            shared = (2 * d * (self.num_heads + 2 * self.num_kv_heads)
+                      * self.head_dim + hd_all * d + mlp(self.d_ff) + 3 * d)
+            n += self.num_mem_blocks * shared
+            # each invocation: LoRA on the MLP's gate/up, a d x d linear
+            per_inv = (d * self.adapter_rank
+                       + self.adapter_rank * 2 * self.d_ff + d * d)
+            n += len(self.hybrid_layer_ids) * per_inv
         elif self.family == "moe":
             dense_l = self.first_dense_layers
             n += dense_l * (per_attn + mlp(self.d_ff) + 2 * d)
@@ -163,7 +180,7 @@ class ModelConfig:
     def reduced(self, **overrides) -> "ModelConfig":
         """A tiny same-family variant for CPU smoke tests."""
         base = dict(
-            num_layers=2 if self.attn_every == 0 else max(2, self.attn_every),
+            num_layers=2,
             d_model=64,
             vocab_size=256,
             vocab_pad_multiple=32,
@@ -184,8 +201,12 @@ class ModelConfig:
             base.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=32)
         if self.mrope_sections:
             base.update(mrope_sections=(2, 3, 3))
-        if self.attn_every:
-            base.update(num_layers=4, attn_every=2)
+        if self.hybrid_layer_ids:
+            # irregular spacing, three invocations over two shared blocks;
+            # attention reads concat(h, x0): head_dim = 2 d / heads
+            base.update(num_layers=6, hybrid_layer_ids=(1, 3, 4),
+                        num_mem_blocks=2, adapter_rank=8, head_dim=32,
+                        ssm_groups=2)
         base.update(overrides)
         return dataclasses.replace(self, **base)
 

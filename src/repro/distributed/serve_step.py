@@ -37,14 +37,21 @@ def sample(logits: jnp.ndarray, key, temperature: float = 0.0,
     return jax.random.categorical(key, logits / temperature, axis=-1)
 
 
+# attention cache leaves, and how far from the end their sequence axis lies
+KV_SEQ_FROM_END = {"k": 3, "v": 3, "c_kv": 2, "k_rope": 2}
+
+
 def pad_cache(cache: Dict[str, Any], cfg: ModelConfig, max_len: int
               ) -> Dict[str, Any]:
     """Grow prefill-sized caches (seq dim == prompt len) to ``max_len`` so
-    decode can append. Seq dim is axis 2 of k/v/c_kv/k_rope leaves."""
+    decode can append. The seq dim is the third axis from the end of k/v
+    (..., B, S, KV, hd) and the second from the end of c_kv/k_rope
+    (..., B, S, r): leaves may be stacked over layers or held one per
+    shared-block invocation (hybrid)."""
     def grow(path, leaf):
         name = str(path[-1].key) if hasattr(path[-1], "key") else str(path[-1])
-        if name in ("k", "v", "c_kv", "k_rope"):
-            seq_ax = 2
+        if name in KV_SEQ_FROM_END:
+            seq_ax = leaf.ndim - KV_SEQ_FROM_END[name]
             cur = leaf.shape[seq_ax]
             if cur < max_len:
                 pad = [(0, 0)] * leaf.ndim

@@ -210,11 +210,12 @@ def cache_pspec(cfg: ModelConfig, mesh: Mesh, batch_size: int) -> Any:
     bax = axes if axes else None
     sax = DATA_AXIS if seq_parallel else None
 
-    def kv_spec(leaf_name: str) -> P:
+    def kv_spec(leaf_name: str) -> Tuple:
+        # trailing axes; a leading layer axis, where stacked, is replicated
         if cfg.use_mla:
             # (L,B,Smax,r) / (L,B,Smax,rope_d): latent is tiny, replicate last
-            return P(None, bax, sax, None)
-        return P(None, bax, sax, kv_tp, None)
+            return (bax, sax, None)
+        return (bax, sax, kv_tp, None)
 
     def spec_for(path: str, ndim: int) -> P:
         name = path.split("/")[-1]

@@ -7,9 +7,11 @@ depth: cost(L) = fixed + L * per_layer. We compile the *unrolled* model at two
 small depths and solve exactly; the scanned full-depth compile is still
 performed for the memory analysis and as the deliverable artifact.
 
-Hybrid (zamba2) is affine in the number of (6 ssm + shared-attn) groups; the
-3-layer ssm tail is counted as 0.5 group (<0.5% error, documented in
-EXPERIMENTS.md §Dry-run).
+Hybrid (zamba2) is affine in the number of periods of its layer pattern: one
+shared-block invocation and the mamba2 layers up to the next one (6 in
+zamba2-7b, whose ids are 6, 11, 17, ..., 77). Depth is counted in whole
+layers (81 / 6 = 13.5 periods), so the model's 13 invocations count as 13.5:
+one invocation over, about 2.6% of the shared-block work.
 """
 from __future__ import annotations
 
@@ -22,13 +24,14 @@ def probe_depths(cfg: ModelConfig) -> Tuple[Dict, Dict, float, float, float]:
     """Returns (overrides_a, overrides_b, n_a, n_b, n_target) where n_* count
     the varied stack units (layers or hybrid groups)."""
     if cfg.family == "hybrid":
-        ae = cfg.attn_every
-        g = cfg.num_layers // ae
-        tail = cfg.num_layers - g * ae
-        n_target = g + tail / ae
-        return ({"num_layers": ae, "scan_layers": False},
-                {"num_layers": 2 * ae, "scan_layers": False},
-                1.0, 2.0, n_target)
+        # probes end just after the second and third invocations
+        ids = cfg.hybrid_layer_ids
+        period = ids[2] - ids[1]
+        return ({"num_layers": ids[1] + 1, "hybrid_layer_ids": ids[:2],
+                 "scan_layers": False},
+                {"num_layers": ids[2] + 1, "hybrid_layer_ids": ids[:3],
+                 "scan_layers": False},
+                2.0, 3.0, cfg.num_layers / period)
     fd = cfg.first_dense_layers
     la, lb = fd + 2, fd + 4
     n_target = cfg.num_layers - fd

@@ -112,7 +112,7 @@ def model_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
             qk_dim = ((cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
                       if cfg.use_mla else cfg.head_dim)
             n_attn = (cfg.num_layers if cfg.family != "hybrid"
-                      else cfg.num_layers // max(1, cfg.attn_every))
+                      else len(cfg.hybrid_layer_ids))
             # causal: S^2/2 per pair of matmuls (QK^T, AV)
             attn = (2.0 * 2.0 * cfg.num_heads * qk_dim
                     * shape.seq_len ** 2 / 2 * shape.global_batch * n_attn)
@@ -124,7 +124,7 @@ def model_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
         qk_dim = ((cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
                   if cfg.use_mla else cfg.head_dim)
         n_attn = (cfg.num_layers if cfg.family != "hybrid"
-                  else cfg.num_layers // max(1, cfg.attn_every))
+                  else len(cfg.hybrid_layer_ids))
         attn = 2.0 * 2.0 * cfg.num_heads * qk_dim * shape.seq_len * toks * n_attn
     ssm = 0.0
     if cfg.ssm_state:

@@ -14,8 +14,9 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ModelConfig
-from repro.distributed.serve_step import (make_decode_step, make_prefill_step,
-                                          pad_cache, sample)
+from repro.distributed.serve_step import (KV_SEQ_FROM_END, make_decode_step,
+                                          make_prefill_step, pad_cache,
+                                          sample)
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 
@@ -35,25 +36,58 @@ def serve_steps(cfg: ModelConfig):
             jax.jit(make_decode_step(cfg), donate_argnums=(2,)))
 
 
+def cache_bytes(cache) -> Dict[str, int]:
+    """Bytes a decode cache holds, by kind: attention KV (``kv_bytes``) and
+    SSM state with its convolution windows (``ssm_bytes``)."""
+    out = {"kv_bytes": 0, "ssm_bytes": 0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        name = getattr(path[-1], "key", None)
+        if name in KV_SEQ_FROM_END:
+            out["kv_bytes"] += leaf.nbytes
+        elif name == "state" or str(name).startswith("conv_"):
+            out["ssm_bytes"] += leaf.nbytes
+    return out
+
+
 def generate(params, cfg: ModelConfig, prompts: jnp.ndarray, *,
              max_new_tokens: int = 32, temperature: float = 0.0,
-             key=None, mesh=None) -> jnp.ndarray:
-    """prompts (B, S) int32 -> (B, S + max_new_tokens)."""
+             key=None, mesh=None, stamps: Optional[Dict] = None
+             ) -> jnp.ndarray:
+    """prompts (B, S) int32 -> (B, S + max_new_tokens).
+
+    Prefill and decode run under the profiler annotations ``serve:prefill``
+    and ``serve:decode``. Given ``stamps``, fills it with host-clock
+    (``time.perf_counter``) stamps ``prefill_dispatch``, ``first_token``
+    (the prefill's token is ready) and ``last_token``, and the counters
+    ``decode_steps`` and ``kv_bytes`` / ``ssm_bytes`` (the decode cache's
+    bytes by kind); the first token is then waited for before decoding."""
     B, S = prompts.shape
     key = key if key is not None else jax.random.PRNGKey(0)
     prefill, decode = serve_steps(cfg)
 
-    batch = {"tokens": prompts, "positions": positions(cfg, B, S)}
-    logits, cache = prefill(params, batch)
-    cache = pad_cache(cache, cfg, S + max_new_tokens)
-    tokens = [sample(logits, key, temperature, cfg.vocab_size)]
+    with jax.profiler.TraceAnnotation("serve:prefill"):
+        if stamps is not None:
+            stamps["prefill_dispatch"] = time.perf_counter()
+        batch = {"tokens": prompts, "positions": positions(cfg, B, S)}
+        logits, cache = prefill(params, batch)
+        cache = pad_cache(cache, cfg, S + max_new_tokens)
+        tokens = [sample(logits, key, temperature, cfg.vocab_size)]
+        if stamps is not None:
+            tokens[0].block_until_ready()
+            stamps["first_token"] = time.perf_counter()
+            stamps.update(cache_bytes(cache))
     out = [prompts]
-    for t in range(max_new_tokens - 1):
-        key, sub = jax.random.split(key)
-        db = {"tokens": tokens[-1],
-              "positions": positions(cfg, B, 1, start=S + t)}
-        logits, cache = decode(params, db, cache)
-        tokens.append(sample(logits, sub, temperature, cfg.vocab_size))
+    with jax.profiler.TraceAnnotation("serve:decode"):
+        for t in range(max_new_tokens - 1):
+            key, sub = jax.random.split(key)
+            db = {"tokens": tokens[-1],
+                  "positions": positions(cfg, B, 1, start=S + t)}
+            logits, cache = decode(params, db, cache)
+            tokens.append(sample(logits, sub, temperature, cfg.vocab_size))
+        if stamps is not None:
+            tokens[-1].block_until_ready()
+            stamps["last_token"] = time.perf_counter()
+            stamps["decode_steps"] = max_new_tokens - 1
     return jnp.concatenate(out + tokens, axis=1)
 
 

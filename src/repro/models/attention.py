@@ -76,13 +76,16 @@ def attn_core(q, k, v, *, scale: float, q_offset=0, kv_valid_len=None,
 
 
 # ================================================================== GQA layer
-def init_gqa(key, cfg: ModelConfig, dtype):
+def init_gqa(key, cfg: ModelConfig, dtype, d_in: Optional[int] = None):
+    """Q/K/V read ``d_in`` channels (d_model unless given: zamba2's shared
+    blocks read concat(h, x0), 2 d_model); the output is d_model wide."""
     H, KV, hd, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.d_model
+    d_in = d_in or d
     kq, kk, kv, ko = jax.random.split(key, 4)
     return {
-        "wq": init_linear(kq, d, H * hd, dtype, bias=cfg.qkv_bias),
-        "wk": init_linear(kk, d, KV * hd, dtype, bias=cfg.qkv_bias),
-        "wv": init_linear(kv, d, KV * hd, dtype, bias=cfg.qkv_bias),
+        "wq": init_linear(kq, d_in, H * hd, dtype, bias=cfg.qkv_bias),
+        "wk": init_linear(kk, d_in, KV * hd, dtype, bias=cfg.qkv_bias),
+        "wv": init_linear(kv, d_in, KV * hd, dtype, bias=cfg.qkv_bias),
         "wo": init_linear(ko, H * hd, d, dtype,
                           stddev=1.0 / math.sqrt(H * hd * 2 * cfg.num_layers)),
     }
@@ -96,8 +99,10 @@ def gqa_rope(cfg: ModelConfig, q, k, positions):
     return apply_rope(q, cos, sin), apply_rope(k, cos, sin)
 
 
-def gqa_full(p, x, cfg: ModelConfig, positions, *, return_kv: bool = False):
-    """Full-sequence causal attention (train / prefill).
+def gqa_full(p, x, cfg: ModelConfig, positions, *, return_kv: bool = False,
+             scale: Optional[float] = None):
+    """Full-sequence causal attention (train / prefill); scores scaled by
+    ``scale``, 1/sqrt(head_dim) unless given.
 
     Returns (out, (k, v) or None). positions: (B,S) or (3,B,S) for mrope.
     """
@@ -107,14 +112,16 @@ def gqa_full(p, x, cfg: ModelConfig, positions, *, return_kv: bool = False):
     k = linear(p["wk"], x).reshape(B, S, KV, hd)
     v = linear(p["wv"], x).reshape(B, S, KV, hd)
     q, k = gqa_rope(cfg, q, k, positions)
-    o = attn_core(q, k, v, scale=1.0 / math.sqrt(hd), use_pallas=cfg.use_pallas)
+    o = attn_core(q, k, v, scale=scale or 1.0 / math.sqrt(hd),
+                  use_pallas=cfg.use_pallas)
     out = linear(p["wo"], o.reshape(B, S, H * hd))
     return out, ((k, v) if return_kv else None)
 
 
-def gqa_decode(p, x, cfg: ModelConfig, positions, k_cache, v_cache, index):
-    """Single-token decode. x (B,1,d); caches (B,Smax,KV,hd); index = #tokens
-    already cached. Returns (out, new_k_cache, new_v_cache)."""
+def gqa_decode(p, x, cfg: ModelConfig, positions, k_cache, v_cache, index,
+               scale: Optional[float] = None):
+    """Single-token decode. x (B,1,d_in); caches (B,Smax,KV,hd); index =
+    #tokens already cached. Returns (out, new_k_cache, new_v_cache)."""
     B = x.shape[0]
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = linear(p["wq"], x).reshape(B, 1, H, hd)
@@ -125,7 +132,7 @@ def gqa_decode(p, x, cfg: ModelConfig, positions, k_cache, v_cache, index):
                                                   index, axis=1)
     v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v.astype(v_cache.dtype),
                                                   index, axis=1)
-    o = attn_core(q, k_cache, v_cache, scale=1.0 / math.sqrt(hd),
+    o = attn_core(q, k_cache, v_cache, scale=scale or 1.0 / math.sqrt(hd),
                   q_offset=index, kv_valid_len=index + 1,
                   use_pallas=cfg.use_pallas)
     out = linear(p["wo"], o.reshape(B, 1, H * hd))

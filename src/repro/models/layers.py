@@ -49,6 +49,18 @@ def rmsnorm(p, x, eps: float):
     return (y * (1.0 + p["scale"].astype(jnp.float32))).astype(dt)
 
 
+def group_rmsnorm(p, x, eps: float, groups: int):
+    """RMSNorm of each of ``groups`` equal slices of the last axis on its
+    own (Mamba2's gated norm with B/C groups); one group is ``rmsnorm``."""
+    if groups == 1:
+        return rmsnorm(p, x, eps)
+    dt = x.dtype
+    xg = x.astype(jnp.float32).reshape(x.shape[:-1] + (groups, -1))
+    var = jnp.mean(jnp.square(xg), axis=-1, keepdims=True)
+    y = (xg * jax.lax.rsqrt(var + eps)).reshape(x.shape)
+    return (y * (1.0 + p["scale"].astype(jnp.float32))).astype(dt)
+
+
 # ---------------------------------------------------------------------- embedding
 def init_embedding(key, vocab: int, d: int, dtype):
     # 1/sqrt(d): keeps tied-unembedding logits O(1); gemma's sqrt(d) input
@@ -149,18 +161,20 @@ def init_mlp(key, cfg: ModelConfig, d_ff: int, dtype):
     return p
 
 
-def _act(name: str, x):
+def act(name: str, x):
     if name == "silu":
         return jax.nn.silu(x)
     if name == "gelu":
         return jax.nn.gelu(x, approximate=True)
+    if name == "gelu_exact":
+        return jax.nn.gelu(x, approximate=False)
     raise ValueError(name)
 
 
 def mlp(p, x, cfg: ModelConfig, d_ff_override: Optional[int] = None):
     h = linear(p["w_in"], x)
     if cfg.gated_mlp:
-        h = _act(cfg.act, linear(p["w_gate"], x)) * h
+        h = act(cfg.act, linear(p["w_gate"], x)) * h
     else:
-        h = _act(cfg.act, h)
+        h = act(cfg.act, h)
     return linear(p["w_out"], h)

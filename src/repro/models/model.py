@@ -4,8 +4,11 @@ One functional model, four families of layer stack:
   * dense / audio / vlm : [norm→attn, norm→mlp] × L, scanned
   * moe                 : optional leading dense layers + [norm→attn, norm→moe] × L
   * ssm                 : [norm→mamba2] × L, scanned
-  * hybrid (zamba2)     : groups of ``attn_every`` mamba2 layers, each followed by
-                          ONE weight-shared attention+MLP block; scanned over groups
+  * hybrid (zamba2)     : [norm→mamba2] × L, scanned in runs between the layers
+                          of ``hybrid_layer_ids``; the j-th such layer's mixer
+                          input also gets shared block j mod ``num_mem_blocks``
+                          (attention+MLP over concat(h, x0)) with invocation
+                          j's own LoRA and linear (equations: configs/zamba2_7b.py)
 
 Layers are stacked (leading L dim) and executed with ``lax.scan`` so the HLO
 stays small at 512-device SPMD compiles; ``cfg.remat`` selects the activation
@@ -17,7 +20,7 @@ logits + filled caches; ``decode(...)`` single-token step against caches.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,12 +64,6 @@ def _scan(cfg: ModelConfig, body, carry, xs):
 
 def _split_stack(key, n: int):
     return jax.random.split(key, n)
-
-
-def _hybrid_layout(cfg: ModelConfig) -> Tuple[int, int]:
-    """(#full groups of ``attn_every`` ssm layers, #tail ssm layers)."""
-    g = cfg.num_layers // cfg.attn_every
-    return g, cfg.num_layers - g * cfg.attn_every
 
 
 # ================================================================ block: dense
@@ -127,16 +124,110 @@ def init_ssm_block(key, cfg: ModelConfig, dtype):
             "ssm": ssm_lib.init_mamba2(key, cfg, dtype)}
 
 
-def ssm_block_full(p, x, cfg: ModelConfig, *, return_cache: bool):
-    h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
+def ssm_block_full(p, x, cfg: ModelConfig, *, return_cache: bool,
+                   inject=None):
+    """x + mamba2(norm(x + inject)): ``inject`` (a shared block's output,
+    zamba2) joins the mixer's input and not the residual."""
+    h = L.rmsnorm(p["norm1"], x if inject is None else x + inject,
+                  cfg.norm_eps)
     h, cache = ssm_lib.mamba2_full(p["ssm"], h, cfg, return_cache=return_cache)
     return x + h, cache
 
 
-def ssm_block_decode(p, x, cfg: ModelConfig, cache):
-    h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
+def ssm_block_decode(p, x, cfg: ModelConfig, cache, inject=None):
+    h = L.rmsnorm(p["norm1"], x if inject is None else x + inject,
+                  cfg.norm_eps)
     h, new_cache = ssm_lib.mamba2_decode(p["ssm"], h, cfg, cache)
     return x + h, new_cache
+
+
+# ========================================================= hybrid (zamba2)
+def hybrid_runs(cfg: ModelConfig) -> List[Tuple[int, int, Optional[int]]]:
+    """The mamba2 layers in runs ``(start, end, j)``: run j starts at the
+    j-th of ``hybrid_layer_ids``, whose mixer input gets the j-th shared
+    block invocation; a run before the first id has j None."""
+    ids = cfg.hybrid_layer_ids
+    bounds = sorted({0, *ids, cfg.num_layers})
+    return [(a, b, ids.index(a) if a in ids else None)
+            for a, b in zip(bounds[:-1], bounds[1:])]
+
+
+def init_shared_block(key, cfg: ModelConfig, dtype):
+    """One shared block: attention from concat(h, x0) (2 d wide) to d, and
+    a gated MLP; no residual of its own."""
+    k1, k2 = jax.random.split(key)
+    d = cfg.d_model
+    return {"norm_in": L.init_rmsnorm(2 * d, dtype),
+            "attn": attn.init_gqa(k1, cfg, dtype, d_in=2 * d),
+            "norm_ff": L.init_rmsnorm(d, dtype),
+            "mlp": L.init_mlp(k2, cfg, cfg.d_ff, dtype)}
+
+
+def init_invocation(key, cfg: ModelConfig, dtype):
+    """What one invocation of a shared block owns: the LoRA (A: d -> rank,
+    B: rank -> 2 d_ff) on its MLP's gate/up projection, and the d x d
+    linear after the block."""
+    ka, kb, kl = jax.random.split(key, 3)
+    d, r = cfg.d_model, cfg.adapter_rank
+    return {"lora_a": L.init_linear(ka, d, r, dtype),
+            "lora_b": L.init_linear(kb, r, 2 * cfg.d_ff, dtype),
+            "linear": L.init_linear(kl, d, d, dtype)}
+
+
+def shared_block(p, inv, h, x0, cfg: ModelConfig, positions, *, kv=None,
+                 index=None, return_kv: bool = False):
+    """Invocation ``inv`` of shared block ``p``: its output t, and its KV
+    cache. Full sequence when ``kv`` is None (the KV returned with
+    ``return_kv``), else one token against ``kv`` at ``index``. Scores are
+    scaled by (head_dim / 2)^-1/2, as zamba2 scales them."""
+    a = L.rmsnorm(p["norm_in"], jnp.concatenate([h, x0], axis=-1),
+                  cfg.norm_eps)
+    scale = (cfg.head_dim / 2) ** -0.5
+    if kv is None:
+        o, new = attn.gqa_full(p["attn"], a, cfg, positions,
+                               return_kv=return_kv, scale=scale)
+        new = None if new is None else {"k": new[0], "v": new[1]}
+    else:
+        o, ck, cv = attn.gqa_decode(p["attn"], a, cfg, positions, kv["k"],
+                                    kv["v"], index, scale=scale)
+        new = {"k": ck, "v": cv}
+    m = L.rmsnorm(p["norm_ff"], o, cfg.norm_eps)
+    ff = cfg.d_ff
+    lora = L.linear(inv["lora_b"], L.linear(inv["lora_a"], m))
+    g = L.linear(p["mlp"]["w_gate"], m) + lora[..., :ff]
+    u = L.linear(p["mlp"]["w_in"], m) + lora[..., ff:]
+    t = L.linear(p["mlp"]["w_out"], L.act(cfg.act, g) * u)
+    return L.linear(inv["linear"], t), new
+
+
+def _hybrid_stack(cfg: ModelConfig, params, x, invoke, layer, caches, *,
+                  remat: bool):
+    """The hybrid trunk from x0 = x: each run of mamba2 layers, scanned over
+    the run's own stack of layers (and of SSM caches, where ``caches``), its
+    first layer's mixer fed the output of its shared block invocation.
+    ``invoke(shared, inv, h, x0, j) -> (t, kv)`` runs invocation j;
+    ``layer(h, t, p[, cache]) -> (h, new cache or None)`` runs one layer.
+    Returns (h, the runs' SSM caches, the invocations' KV caches)."""
+    x0, h, kvs, new = x, x, [], []
+    for r, (_, _, j) in enumerate(hybrid_runs(cfg)):
+        t = None
+        if j is not None:
+            t, kv = invoke(params["shared"][j % cfg.num_mem_blocks],
+                           params["invocations"][j], h, x0, j)
+            kvs.append(kv)
+
+        def body(c, xs):
+            h, t = c
+            h, out = layer(h, t, *xs)
+            # only the run's first layer is fed the block's output
+            return (h, None if t is None else jnp.zeros_like(t)), out
+
+        xs = (params["layers"][r],) + (() if caches is None else
+                                       (caches[r],))
+        (h, _), out = _scan(cfg, _remat(cfg, body) if remat else body,
+                            (h, t), xs)
+        new.append(out)
+    return h, tuple(new), tuple(kvs)
 
 
 # ====================================================================== params
@@ -156,17 +247,17 @@ def init_params(key, cfg: ModelConfig) -> Params:
             lambda k: init_ssm_block(k, cfg, dtype))(
                 _split_stack(k_layers, cfg.num_layers))
     elif fam == "hybrid":
-        n_groups, tail = _hybrid_layout(cfg)
-        keys = _split_stack(k_layers, n_groups * cfg.attn_every).reshape(
-            n_groups, cfg.attn_every, 2)
-        params["ssm_groups"] = jax.vmap(jax.vmap(
-            lambda k: init_ssm_block(k, cfg, dtype)))(keys)
-        if tail:
-            params["ssm_tail"] = jax.vmap(
-                lambda k: init_ssm_block(k, cfg, dtype))(
-                    _split_stack(jax.random.fold_in(k_layers, 1), tail))
-        params["shared_attn"] = init_dense_block(
-            k_extra, cfg, dtype, use_moe=False, d_ff=cfg.d_ff)
+        # one stack of mamba2 layers per run between invocations
+        stack = jax.vmap(lambda k: init_ssm_block(k, cfg, dtype))(
+            _split_stack(k_layers, cfg.num_layers))
+        params["layers"] = tuple(jax.tree.map(lambda t: t[a:b], stack)
+                                 for a, b, _ in hybrid_runs(cfg))
+        params["shared"] = tuple(
+            init_shared_block(k, cfg, dtype)
+            for k in _split_stack(k_extra, cfg.num_mem_blocks))
+        params["invocations"] = tuple(
+            init_invocation(k, cfg, dtype) for k in _split_stack(
+                jax.random.fold_in(k_extra, 1), len(cfg.hybrid_layer_ids)))
     elif fam == "moe":
         fd = cfg.first_dense_layers
         if fd:
@@ -215,13 +306,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
     if fam == "ssm":
         cache["layers"] = ssm_cache(cfg.num_layers)
     elif fam == "hybrid":
-        n_groups, tail = _hybrid_layout(cfg)
-        cache["ssm_groups"] = jax.tree.map(
-            lambda t: t.reshape((n_groups, cfg.attn_every) + t.shape[1:]),
-            ssm_cache(n_groups * cfg.attn_every))
-        if tail:
-            cache["ssm_tail"] = ssm_cache(tail)
-        cache["attn"] = gqa_cache(n_groups)
+        cache["layers"] = tuple(ssm_cache(b - a)
+                                for a, b, _ in hybrid_runs(cfg))
+        # one KV cache per invocation, though blocks share weights
+        cache["attn"] = tuple(jax.tree.map(lambda t: t[0], gqa_cache(1))
+                              for _ in cfg.hybrid_layer_ids)
     elif fam == "moe" and cfg.first_dense_layers:
         cache["dense_layers"] = gqa_cache(cfg.first_dense_layers)
         cache["layers"] = gqa_cache(cfg.num_layers - cfg.first_dense_layers)
@@ -273,29 +362,18 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
             caches["layers"] = cache
 
     elif fam == "hybrid":
-        n_groups, tail = _hybrid_layout(cfg)
-        shared = params["shared_attn"]
+        def invoke(shared, inv, h, x0, j):
+            return shared_block(shared, inv, h, x0, cfg, positions,
+                                return_kv=prefill)
 
-        def grp_body(carry, grp_params):
-            y = carry
-            def inner(c, lp):
-                out, cache = ssm_block_full(lp, c, cfg, return_cache=prefill)
-                return out, cache
-            y, ssm_c = _scan(cfg, _remat(cfg, inner), y, grp_params)
-            y, kv, _ = dense_block_full(shared, y, cfg, positions,
-                                        use_moe=False, return_kv=prefill)
-            return y, (ssm_c, kv)
-        x, (ssm_caches, kvs) = _scan(cfg, grp_body, x, params["ssm_groups"])
-        if tail:
-            def t_body(c, lp):
-                out, cache = ssm_block_full(lp, c, cfg, return_cache=prefill)
-                return out, cache
-            x, tail_c = _scan(cfg, _remat(cfg, t_body), x, params["ssm_tail"])
+        def layer(h, t, lp):
+            return ssm_block_full(lp, h, cfg, return_cache=prefill, inject=t)
+
+        x, ssm_c, kvs = _hybrid_stack(cfg, params, x, invoke, layer, None,
+                                      remat=True)
         if prefill:
-            caches["ssm_groups"] = ssm_caches
-            if tail:
-                caches["ssm_tail"] = tail_c
-            caches["attn"] = {"k": kvs[0], "v": kvs[1]}
+            caches["layers"] = ssm_c
+            caches["attn"] = kvs
 
     else:                                   # dense / moe / audio / vlm
         fd = cfg.first_dense_layers if fam == "moe" else 0
@@ -358,33 +436,15 @@ def decode(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
         new_cache["layers"] = nc
 
     elif fam == "hybrid":
-        n_groups, tail = _hybrid_layout(cfg)
-        shared = params["shared_attn"]
+        def invoke(shared, inv, h, x0, j):
+            return shared_block(shared, inv, h, x0, cfg, positions,
+                                kv=cache["attn"][j], index=index)
 
-        def grp_body(carry, xs):
-            grp_params, grp_ssm_cache, attn_c = xs
-            y = carry
-            def inner(c, xs2):
-                lp, lc = xs2
-                out, ncache = ssm_block_decode(lp, c, cfg, lc)
-                return out, ncache
-            y, ssm_nc = _scan(cfg, inner, y, (grp_params, grp_ssm_cache))
-            y, attn_nc = dense_block_decode(shared, y, cfg, positions, attn_c,
-                                            index, use_moe=False)
-            return y, (ssm_nc, attn_nc)
-        x, (ssm_nc, attn_nc) = _scan(
-            cfg, grp_body, x,
-            (params["ssm_groups"], cache["ssm_groups"], cache["attn"]))
-        new_cache["ssm_groups"] = ssm_nc
-        new_cache["attn"] = attn_nc
-        if tail:
-            def t_body(c, xs2):
-                lp, lc = xs2
-                out, ncache = ssm_block_decode(lp, c, cfg, lc)
-                return out, ncache
-            x, tail_nc = _scan(cfg, t_body, x,
-                                      (params["ssm_tail"], cache["ssm_tail"]))
-            new_cache["ssm_tail"] = tail_nc
+        def layer(h, t, lp, lc):
+            return ssm_block_decode(lp, h, cfg, lc, inject=t)
+
+        x, new_cache["layers"], new_cache["attn"] = _hybrid_stack(
+            cfg, params, x, invoke, layer, cache["layers"], remat=False)
 
     else:
         fd = cfg.first_dense_layers if fam == "moe" else 0

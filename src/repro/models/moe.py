@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import init_linear, linear, init_mlp, mlp, _act
+from repro.models.layers import init_linear, linear, init_mlp, mlp, act
 
 
 def init_moe(key, cfg: ModelConfig, dtype):
@@ -111,7 +111,7 @@ def moe_apply(p, x: jnp.ndarray, cfg: ModelConfig
     # --- expert GEMMs ---------------------------------------------------------
     h = jnp.einsum("gecd,edf->gecf", x_e, p["w_in"])
     g = jnp.einsum("gecd,edf->gecf", x_e, p["w_gate"])
-    h = _act(cfg.act, g) * h
+    h = act(cfg.act, g) * h
     y_e = jnp.einsum("gecf,efd->gecd", h, p["w_out"])                # (G,E,C,d)
 
     # --- gather back ----------------------------------------------------------

@@ -1,8 +1,9 @@
-"""Mamba2 (SSD) block: projections, causal depthwise convs, SSD scan, gated
-RMSNorm, output projection. Full-sequence (train/prefill) and single-step
-(decode) paths share parameters.
+"""Mamba2 (SSD) block: projections, causal depthwise convs (with bias where
+``cfg.ssm_conv_bias``), SSD scan, gated RMSNorm (per B/C group), output
+projection. Full-sequence (train/prefill) and single-step (decode) paths
+share parameters.
 
-Deviation from the reference fused implementation (documented in DESIGN.md):
+Deviation from the published fused implementation:
 z/x/B/C/dt use separate projection matrices and x/B/C separate depthwise convs
 — mathematically identical to the fused in_proj/conv (depthwise convs are
 per-channel), but each tensor gets a clean mesh sharding.
@@ -16,7 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import init_linear, linear, init_rmsnorm, rmsnorm
+from repro.models.layers import (group_rmsnorm, init_linear, init_rmsnorm,
+                                 linear)
 
 
 def init_mamba2(key, cfg: ModelConfig, dtype):
@@ -30,7 +32,7 @@ def init_mamba2(key, cfg: ModelConfig, dtype):
     dt_bias = dt_init + jnp.log(-jnp.expm1(-dt_init))
     A_log = jnp.log(jax.random.uniform(ks[1], (H,), minval=1.0, maxval=16.0))
     std_conv = 1.0 / math.sqrt(K)
-    return {
+    p = {
         "wz": init_linear(ks[2], d, di, dtype),
         "wx": init_linear(ks[3], d, di, dtype),
         "wB": init_linear(ks[4], d, G * N, dtype),
@@ -46,26 +48,35 @@ def init_mamba2(key, cfg: ModelConfig, dtype):
         "w_out": init_linear(jax.random.fold_in(key, 99), di, d, dtype,
                              stddev=1.0 / math.sqrt(di * 2 * cfg.num_layers)),
     }
+    if cfg.ssm_conv_bias:
+        # a depthwise convolution's default: U(-1/sqrt(K), 1/sqrt(K))
+        for i, (name, c) in enumerate((("conv_x", di), ("conv_B", G * N),
+                                       ("conv_C", G * N))):
+            p[name + "_bias"] = jax.random.uniform(
+                jax.random.fold_in(key, 100 + i), (c,), minval=-std_conv,
+                maxval=std_conv).astype(dtype)
+    return p
 
 
-def causal_conv(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    """Depthwise causal conv. x (B, S, C), w (K, C) -> (B, S, C)."""
+def causal_conv(x: jnp.ndarray, w: jnp.ndarray, b=None) -> jnp.ndarray:
+    """Depthwise causal conv. x (B, S, C), w (K, C), bias b (C,) or None
+    -> (B, S, C)."""
     K = w.shape[0]
     S = x.shape[1]
     xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     y = jnp.zeros_like(x)
     for k in range(K):
         y = y + w[k][None, None, :] * jax.lax.dynamic_slice_in_dim(xp, k, S, axis=1)
-    return y
+    return y if b is None else y + b
 
 
-def causal_conv_step(x_t: jnp.ndarray, w: jnp.ndarray, cache: jnp.ndarray
-                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def causal_conv_step(x_t: jnp.ndarray, w: jnp.ndarray, cache: jnp.ndarray,
+                     b=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x_t (B, C), cache (B, K-1, C) of previous inputs -> (y_t, new_cache)."""
     K = w.shape[0]
     window = jnp.concatenate([cache, x_t[:, None, :]], axis=1)     # (B,K,C)
     y = jnp.einsum("bkc,kc->bc", window, w)
-    return y, window[:, 1:, :]
+    return (y if b is None else y + b), window[:, 1:, :]
 
 
 def _ssd_dispatch(cfg: ModelConfig, x4, dt, A, B4, C4, h0=None,
@@ -89,9 +100,9 @@ def mamba2_full(p, x, cfg: ModelConfig, *, return_cache: bool = False):
     C_raw = linear(p["wC"], x)
     dt_raw = linear(p["wdt"], x)
 
-    xin = jax.nn.silu(causal_conv(xin_raw, p["conv_x"]))
-    Bc = jax.nn.silu(causal_conv(B_raw, p["conv_B"]))
-    Cc = jax.nn.silu(causal_conv(C_raw, p["conv_C"]))
+    xin = jax.nn.silu(causal_conv(xin_raw, p["conv_x"], p.get("conv_x_bias")))
+    Bc = jax.nn.silu(causal_conv(B_raw, p["conv_B"], p.get("conv_B_bias")))
+    Cc = jax.nn.silu(causal_conv(C_raw, p["conv_C"], p.get("conv_C_bias")))
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
 
     x4 = xin.reshape(B, S, H, P)
@@ -104,7 +115,7 @@ def mamba2_full(p, x, cfg: ModelConfig, *, return_cache: bool = False):
     y4 = y4 + (p["D"][None, None, :, None] * x4.astype(jnp.float32)).astype(y4.dtype)
 
     y = y4.reshape(B, S, di)
-    y = rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
+    y = group_rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps, G)
     out = linear(p["w_out"], y)
 
     cache = None
@@ -139,9 +150,12 @@ def mamba2_decode(p, x, cfg: ModelConfig, cache):
     C_raw = linear(p["wC"], xt)
     dt_raw = linear(p["wdt"], xt)
 
-    xin, conv_x = causal_conv_step(xin_raw, p["conv_x"], cache["conv_x"])
-    Bc, conv_B = causal_conv_step(B_raw, p["conv_B"], cache["conv_B"])
-    Cc, conv_C = causal_conv_step(C_raw, p["conv_C"], cache["conv_C"])
+    xin, conv_x = causal_conv_step(xin_raw, p["conv_x"], cache["conv_x"],
+                                   p.get("conv_x_bias"))
+    Bc, conv_B = causal_conv_step(B_raw, p["conv_B"], cache["conv_B"],
+                                  p.get("conv_B_bias"))
+    Cc, conv_C = causal_conv_step(C_raw, p["conv_C"], cache["conv_C"],
+                                  p.get("conv_C_bias"))
     xin, Bc, Cc = jax.nn.silu(xin), jax.nn.silu(Bc), jax.nn.silu(Cc)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
 
@@ -151,7 +165,7 @@ def mamba2_decode(p, x, cfg: ModelConfig, cache):
     y3 = y3 + (p["D"][None, :, None]
                * xin.reshape(B, H, P).astype(jnp.float32)).astype(y3.dtype)
     y = y3.reshape(B, di)
-    y = rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
+    y = group_rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps, G)
     out = linear(p["w_out"], y)[:, None, :]
     new_cache = {"conv_x": conv_x, "conv_B": conv_B, "conv_C": conv_C, "state": h}
     return out, new_cache

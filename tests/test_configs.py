@@ -11,7 +11,7 @@ PUBLISHED_B = {
     "phi3.5-moe-42b-a6.6b": (41.9, 0.1),
     "deepseek-v2-lite-16b": (15.7, 0.1),
     "musicgen-medium": (1.4, 0.25),
-    "zamba2-7b": (6.8, 0.15),
+    "zamba2-7b": (7.4, 0.05),        # 2 shared blocks, 13 invocations
     "chatglm3-6b": (6.2, 0.1),
     "stablelm-3b": (2.8, 0.1),
     "gemma-7b": (8.5, 0.1),

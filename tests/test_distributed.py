@@ -82,7 +82,7 @@ def test_replicated_kv_rule():
     assert tuple(spec) in ((None, None, None), (None, None)) or \
         spec[-1] is None
     zam = get_config("zamba2-7b")
-    spec = SH.param_spec(zam, mesh, "shared_attn/attn/wk/w", 2)
+    spec = SH.param_spec(zam, mesh, "shared/[0]/attn/wk/w", 2)
     assert spec[-1] == "model"
     # musicgen kv=24: not divisible by 16 -> replicated (arg-level rule)
     mg = get_config("musicgen-medium")

@@ -100,7 +100,7 @@ def test_ssd_pallas_vs_naive(shape, chunk, dtype):
 
 @pytest.mark.parametrize("shape,x_bytes,want", [
     ((24, 1, 64, 128, 256), 2, 24),    # mamba2-130m: one step a layer
-    ((112, 1, 64, 64, 256), 2, 28),    # zamba2-7b: 4 steps, 1792 lanes
+    ((112, 2, 64, 64, 256), 2, 28),    # zamba2-7b: 2 steps a group, 1792 lanes
     ((6, 2, 512, 512, 128), 4, 1),     # VMEM forces blocks under a group
     ((4, 2, 16, 32, 32), 4, 2),        # no tileable block: the whole group
 ])

@@ -1,7 +1,9 @@
 """Compile each Pallas kernel for a described TPU v5e chip at the widths the
 chip smoke runs (stablelm-3b attention, mamba2-130m SSD, stablelm-3b
 rmsnorm), and the SSD kernel also as the stream benchmark's scoring forward
-calls it and at zamba2-7b's heads. Nothing runs: the chip's compiler
+calls it and at zamba2-7b's heads and groups, and flash and decode
+attention at zamba2-7b's head width (224, not a multiple of 128 lanes).
+Nothing runs: the chip's compiler
 refuses here, at no chip time, what interpret mode cannot see (unaligned
 blocks, unlowerable primitives, blocks that overflow VMEM).
 
@@ -76,11 +78,12 @@ def test_ssd_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("b,s,h,n,return_state", [
-    (1, 256, SSD_H, SSD_N, False),   # a 1 x 256 scoring task, no state
-    (1, 2048, 112, 64, True),        # zamba2-7b: 112 heads of state 64
-])
-def test_ssd_head_blocks_compile_for_v5e(one_chip, b, s, h, n, return_state):
+@pytest.mark.parametrize("b,s,h,g,n,return_state", [
+    (1, 256, SSD_H, 1, SSD_N, False),   # a 1 x 256 scoring task, no state
+    (4, 1024, 112, 2, 64, True),        # zamba2-7b: 112 heads, 2 groups of
+])                                      # state 64, as generate.zamba2 runs
+def test_ssd_head_blocks_compile_for_v5e(one_chip, b, s, h, g, n,
+                                         return_state):
     """A head block whose blocks and temporaries overflow VMEM, or whose
     lanes the chip cannot tile, is refused here."""
     bf, f32 = jnp.bfloat16, jnp.float32
@@ -88,7 +91,32 @@ def test_ssd_head_blocks_compile_for_v5e(one_chip, b, s, h, n, return_state):
         lambda x, dt, a, bm, cm: ssd_pallas(x, dt, a, bm, cm, chunk=CHUNK,
                                             return_state=return_state),
         one_chip, ((b, s, h, SSD_P), bf), ((b, s, h), f32), ((h,), f32),
-        ((b, s, 1, n), bf), ((b, s, 1, n), bf))
+        ((b, s, g, n), bf), ((b, s, g, n), bf))
+    assert "tpu_custom_call" in txt
+
+
+# zamba2-7b's shared attention as generate.zamba2 runs it: 4 x 1024 prompts,
+# 32 heads of 224, a cache of 1024 + 32
+Z_B, Z_S, Z_H, Z_HD, Z_CACHE = 4, 1024, 32, 224, 1056
+
+
+def test_flash_attention_at_head_dim_224_compiles_for_v5e(one_chip):
+    bf = jnp.bfloat16
+    txt = _compile_text(
+        lambda q, k, v: flash_attention_bhsd(q, k, v,
+                                             scale=(Z_HD / 2) ** -0.5),
+        one_chip, ((Z_B, Z_H, Z_S, Z_HD), bf), ((Z_B, Z_H, Z_S, Z_HD), bf),
+        ((Z_B, Z_H, Z_S, Z_HD), bf))
+    assert "tpu_custom_call" in txt
+
+
+def test_decode_attention_at_head_dim_224_compiles_for_v5e(one_chip):
+    bf = jnp.bfloat16
+    txt = _compile_text(
+        lambda q, k, v, n: decode_attention_bhd(q, k, v, n,
+                                                scale=(Z_HD / 2) ** -0.5),
+        one_chip, ((Z_B, Z_H, 1, Z_HD), bf), ((Z_B, Z_H, Z_CACHE, Z_HD), bf),
+        ((Z_B, Z_H, Z_CACHE, Z_HD), bf), ((), jnp.int32))
     assert "tpu_custom_call" in txt
 
 
