@@ -156,10 +156,11 @@ def run_kernels(widths=KERNEL_WIDTHS, *, interpret: bool = False) -> dict:
 
     q1 = q[:, :, :1]
     valid = S - S // 4
-    got = decode_attention_bhd(q1, k, v, valid, scale=scale,
+    kc, vc = jnp.swapaxes(k, 2, 3), jnp.swapaxes(v, 2, 3)   # decode's cache
+    got = decode_attention_bhd(q1, kc, vc, valid, scale=scale,
                                interpret=interpret)
     with exact:
-        want = da_ref.decode_attention_ref(q1, k, v, valid, scale=scale)
+        want = da_ref.decode_attention_ref(q1, kc, vc, valid, scale=scale)
     errs["decode_attention"] = _rel_err(got, want)
 
     Hs, P, N = w["ssd_heads"], w["ssd_p"], w["ssd_n"]

@@ -215,7 +215,7 @@ def cache_pspec(cfg: ModelConfig, mesh: Mesh, batch_size: int) -> Any:
         if cfg.use_mla:
             # (L,B,Smax,r) / (L,B,Smax,rope_d): latent is tiny, replicate last
             return (bax, sax, None)
-        return (bax, sax, kv_tp, None)
+        return (bax, kv_tp, None, sax)          # (L,B,KV,hd,Smax)
 
     def spec_for(path: str, ndim: int) -> P:
         name = path.split("/")[-1]

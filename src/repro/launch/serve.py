@@ -49,6 +49,13 @@ def cache_bytes(cache) -> Dict[str, int]:
     return out
 
 
+def cache_len(n: int) -> int:
+    """Decode's cache length for ``n`` positions: whole 128-lane tiles, as
+    the chip lays out a cache whose sequence axis is last, so the decode
+    attention kernel reads it in 128-aligned blocks with no pad."""
+    return -(-n // 128) * 128
+
+
 def generate(params, cfg: ModelConfig, prompts: jnp.ndarray, *,
              max_new_tokens: int = 32, temperature: float = 0.0,
              key=None, mesh=None, stamps: Optional[Dict] = None
@@ -70,7 +77,7 @@ def generate(params, cfg: ModelConfig, prompts: jnp.ndarray, *,
             stamps["prefill_dispatch"] = time.perf_counter()
         batch = {"tokens": prompts, "positions": positions(cfg, B, S)}
         logits, cache = prefill(params, batch)
-        cache = pad_cache(cache, cfg, S + max_new_tokens)
+        cache = pad_cache(cache, cfg, cache_len(S + max_new_tokens))
         tokens = [sample(logits, key, temperature, cfg.vocab_size)]
         if stamps is not None:
             tokens[0].block_until_ready()

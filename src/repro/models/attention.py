@@ -22,33 +22,28 @@ NEG_INF = -2.0e38
 
 
 # ============================================================ core (XLA path)
-def attn_weights_core(q, k, *, scale: float, q_offset, kv_valid_len) -> jnp.ndarray:
-    """Grouped-query causal attention scores+softmax.
+def attn_weights_core(q, k, *, scale: float) -> jnp.ndarray:
+    """Grouped-query causal attention scores+softmax, q and k both from
+    position 0.
 
     q: (B, Sq, KV, G, hd); k: (B, Sk, KV, hd). Returns weights (B,KV,G,Sq,Sk) f32.
-    ``q_offset``: position of q[0] in the global sequence (scalar, traced ok).
-    ``kv_valid_len``: number of valid cache entries (None -> all Sk valid).
     """
-    B, Sq, KV, G, hd = q.shape
-    Sk = k.shape[1]
+    Sq, Sk = q.shape[1], k.shape[1]
     scores = jnp.einsum("bqkgd,bskd->bkgqs", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
-    q_pos = q_offset + jnp.arange(Sq)
-    k_pos = jnp.arange(Sk)
-    mask = k_pos[None, :] <= q_pos[:, None]                      # causal
-    if kv_valid_len is not None:
-        mask = mask & (k_pos[None, :] < kv_valid_len)
+    mask = jnp.arange(Sk)[None, :] <= jnp.arange(Sq)[:, None]    # causal
     scores = jnp.where(mask[None, None, None], scores, NEG_INF)
     return jax.nn.softmax(scores, axis=-1)
 
 
-def attn_core(q, k, v, *, scale: float, q_offset=0, kv_valid_len=None,
-              use_pallas: bool = False) -> jnp.ndarray:
-    """q (B,Sq,H,hd), k/v (B,Sk,KV,hd) -> (B,Sq,H,hd_v).
+def attn_core(q, k, v, *, scale: float, use_pallas: bool = False
+              ) -> jnp.ndarray:
+    """Full causal sequence from position 0: q (B,Sq,H,hd), k/v (B,Sk,KV,hd)
+    -> (B,Sq,H,hd_v).
 
-    ``use_pallas=True`` runs the flash kernel (full causal sequence from
-    position 0) or the decode kernel (one query against a cache); any other
-    case raises rather than silently running the jnp path."""
+    ``use_pallas=True`` runs the flash kernel; a value width other than the
+    query's (MLA) raises rather than silently running the jnp path. Decode
+    against a cache is ``gqa_decode``'s own (the decode attention kernel)."""
     B, Sq, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
@@ -57,20 +52,10 @@ def attn_core(q, k, v, *, scale: float, q_offset=0, kv_valid_len=None,
             raise ValueError(
                 f"use_pallas: no Pallas attention kernel for value width "
                 f"{v.shape[-1]} != query width {hd} (MLA); use_pallas=False")
-        if Sq > 1 and kv_valid_len is None and isinstance(q_offset, int) \
-                and q_offset == 0:
-            from repro.kernels.flash_attention import ops as fa_ops
-            return fa_ops.flash_attention(q, k, v, scale=scale, causal=True)
-        if Sq == 1 and kv_valid_len is not None:
-            from repro.kernels.decode_attention import ops as da_ops
-            return da_ops.decode_attention(q, k, v, kv_valid_len, scale=scale)
-        raise ValueError(
-            f"use_pallas: no Pallas attention kernel for Sq={Sq}, "
-            f"q_offset={q_offset!r}, kv_valid_len={kv_valid_len!r}; "
-            "use_pallas=False")
+        from repro.kernels.flash_attention import ops as fa_ops
+        return fa_ops.flash_attention(q, k, v, scale=scale, causal=True)
     qg = q.reshape(B, Sq, KV, G, hd)
-    w = attn_weights_core(qg, k, scale=scale, q_offset=q_offset,
-                          kv_valid_len=kv_valid_len)
+    w = attn_weights_core(qg, k, scale=scale)
     o = jnp.einsum("bkgqs,bskd->bqkgd", w, v.astype(jnp.float32))
     return o.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
@@ -120,21 +105,27 @@ def gqa_full(p, x, cfg: ModelConfig, positions, *, return_kv: bool = False,
 
 def gqa_decode(p, x, cfg: ModelConfig, positions, k_cache, v_cache, index,
                scale: Optional[float] = None):
-    """Single-token decode. x (B,1,d_in); caches (B,Smax,KV,hd); index =
-    #tokens already cached. Returns (out, new_k_cache, new_v_cache)."""
+    """Single-token decode. x (B,1,d_in); caches (B,KV,hd,Smax), sequence
+    last, as the decode attention kernel reads them in place; index =
+    #tokens already cached. The new (B,KV,hd,1) column is written with one
+    ``dynamic_update_slice`` on the last axis, in place where the caches are
+    donated. Returns (out, new_k_cache, new_v_cache)."""
+    from repro.kernels.decode_attention import ops as da_ops
     B = x.shape[0]
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = linear(p["wq"], x).reshape(B, 1, H, hd)
     k = linear(p["wk"], x).reshape(B, 1, KV, hd)
     v = linear(p["wv"], x).reshape(B, 1, KV, hd)
     q, k = gqa_rope(cfg, q, k, positions)
-    k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k.astype(k_cache.dtype),
-                                                  index, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v.astype(v_cache.dtype),
-                                                  index, axis=1)
-    o = attn_core(q, k_cache, v_cache, scale=scale or 1.0 / math.sqrt(hd),
-                  q_offset=index, kv_valid_len=index + 1,
-                  use_pallas=cfg.use_pallas)
+
+    def put(cache, new):                    # (B,1,KV,hd) -> column at index
+        col = jnp.transpose(new, (0, 2, 3, 1)).astype(cache.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(cache, col, index, axis=3)
+
+    k_cache, v_cache = put(k_cache, k), put(v_cache, v)
+    o = da_ops.decode_attention(q, k_cache, v_cache, index + 1,
+                                scale=scale or 1.0 / math.sqrt(hd),
+                                use_pallas=cfg.use_pallas)
     out = linear(p["wo"], o.reshape(B, 1, H * hd))
     return out, k_cache, v_cache
 

@@ -279,7 +279,13 @@ def init_params(key, cfg: ModelConfig) -> Params:
 
 # ======================================================================= cache
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
-    """Preallocated decoding caches (stacked over layers), plus ``index``."""
+    """Preallocated decoding caches (stacked over layers), plus ``index``.
+
+    GQA ``k``/``v`` are (..., B, KV, hd, max_len), sequence last, as the
+    decode attention kernel reads them; MLA's ``c_kv``/``k_rope`` are
+    (..., B, max_len, r). Exactly ``max_len`` positions: a caller that
+    serves on the chip rounds its length to whole 128-lane tiles itself
+    (``launch/serve.py``)."""
     dtype = jnp.dtype(cfg.dtype)
     fam = cfg.family
 
@@ -289,10 +295,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
                                        cfg.kv_lora_rank), dtype),
                     "k_rope": jnp.zeros((n_layers, batch, max_len,
                                          cfg.qk_rope_head_dim), dtype)}
-        return {"k": jnp.zeros((n_layers, batch, max_len, cfg.num_kv_heads,
-                                cfg.head_dim), dtype),
-                "v": jnp.zeros((n_layers, batch, max_len, cfg.num_kv_heads,
-                                cfg.head_dim), dtype)}
+        kv = (n_layers, batch, cfg.num_kv_heads, cfg.head_dim, max_len)
+        return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
 
     def ssm_cache(n_layers):
         K, di, G, N = cfg.ssm_conv, cfg.ssm_d_inner, cfg.ssm_groups, cfg.ssm_state

@@ -10,6 +10,7 @@ pytest.importorskip("hypothesis",
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.kernels.decode_attention import ref as da_ref
+from repro.kernels.decode_attention.decode_attention import kv_block
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.flash_attention import ref as fa_ref
 from repro.kernels.flash_attention.ops import flash_attention
@@ -54,19 +55,38 @@ def test_flash_attention_vs_oracle(shape, dtype):
     ((2, 512, 4, 2, 64), 301),
     ((1, 1024, 8, 8, 32), 1024),
     ((2, 640, 4, 1, 128), 17),
+    ((2, 512, 4, 2, 64), 5),          # valid inside the first block
+    ((1, 384, 4, 4, 80), 384),        # valid == S, stablelm-3b's width
+    ((1, 200, 4, 4, 32), 150),        # S no multiple of 128: one block
+    ((1, 384, 4, 4, 224), 300),       # zamba2-7b's width, blocks of 128
 ])
 def test_decode_attention_vs_oracle(shape, valid, dtype):
+    """Caches (B, KV, hd, S), sequence last, as decode keeps them."""
     B, S, H, KV, hd = shape
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, 1, H, hd), dtype)
-    k = jax.random.normal(ks[1], (B, S, KV, hd), dtype)
-    v = jax.random.normal(ks[2], (B, S, KV, hd), dtype)
+    k = jax.random.normal(ks[1], (B, KV, hd, S), dtype)
+    v = jax.random.normal(ks[2], (B, KV, hd, S), dtype)
     got = decode_attention(q, k, v, valid, scale=0.1, use_pallas=True,
                            interpret=True, block_k=128)
     want = decode_attention(q, k, v, valid, scale=0.1, use_pallas=False)
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
                                 - want.astype(jnp.float32))))
     assert err < _tol(dtype), f"{shape} valid={valid}: {err}"
+    # positions past valid are masked: garbage there changes nothing
+    junk = jnp.arange(S) >= valid
+    k2, v2 = (jnp.where(junk, jnp.asarray(1e4, dtype), t) for t in (k, v))
+    got2 = decode_attention(q, k2, v2, valid, scale=0.1, use_pallas=True,
+                            interpret=True, block_k=128)
+    assert np.array_equal(np.asarray(got2), np.asarray(got))
+
+
+@pytest.mark.parametrize("S,block_k,want", [
+    (1152, 512, 384), (1152, 128, 128), (640, 512, 128), (512, 512, 512),
+    (2048, 512, 512), (1056, 512, 1056), (41, 512, 41), (128, 512, 128)])
+def test_decode_attention_kv_block(S, block_k, want):
+    """Blocks are whole 128-lane tiles dividing S, or all of S."""
+    assert kv_block(S, block_k) == want
 
 
 # ------------------------------------------------------------------------ ssd
@@ -240,6 +260,8 @@ def test_grad_through_pallas_kernel_names_it(kernel):
         B, S, H, hd = 1, 32, 2, 32
         k = jax.random.normal(ks[1], (B, S, H, hd), jnp.float32)
         v = jax.random.normal(ks[2], (B, S, H, hd), jnp.float32)
+        if kernel == "decode_attention":        # the decode cache's layout
+            k, v = (jnp.transpose(t, (0, 2, 3, 1)) for t in (k, v))
         if kernel == "flash_attention":
             def f(q):
                 return flash_attention(q, k, v, scale=hd ** -0.5,

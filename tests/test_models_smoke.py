@@ -140,3 +140,47 @@ def test_moe_aux_loss_nonzero(rng_key):
     batch = make_batch(cfg, rng_key, 2, 16)
     _, aux, _ = M.forward(params, cfg, batch, mode="train")
     assert float(aux) > 0
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_pad_cache_gives_decode_layout(arch, rng_key):
+    """Prefill's caches, grown by ``pad_cache``, have ``init_cache``'s
+    shapes: GQA k/v turned from (..., B, S, KV, hd) to (..., B, KV, hd, S)
+    with the prompt's positions first and zeros after, MLA latents padded
+    in place. One decode step on them through the Pallas decode kernel
+    (interpret mode) gives the XLA path's logits."""
+    import dataclasses
+
+    import numpy as np
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.distributed.serve_step import pad_cache
+    cfg = get_smoke_config(arch, dtype="float32")
+    B, S, n = 2, 16, 1152              # as generate.zamba2: 3 blocks of 384
+    params = M.init_params(rng_key, cfg)
+    batch = make_batch(cfg, rng_key, B, S, with_labels=False)
+    _, _, cache = M.forward(params, cfg, batch, mode="prefill")
+    grown = pad_cache(cache, cfg, n)
+    want = jax.eval_shape(lambda: M.init_cache(cfg, B, n))
+    assert (jax.tree.map(lambda x: (x.shape, x.dtype), grown)
+            == jax.tree.map(lambda x: (x.shape, x.dtype), want))
+    for (path, old), new in zip(jax.tree_util.tree_flatten_with_path(cache)[0],
+                                jax.tree.leaves(grown)):
+        name = path[-1].key
+        if name in ("k", "v"):
+            old = jnp.moveaxis(old, -3, -1)
+            assert np.array_equal(new[..., :S], old)
+            assert not np.asarray(new[..., S:]).any()
+        elif name in ("c_kv", "k_rope"):
+            assert np.array_equal(new[..., :S, :], old)
+            assert not np.asarray(new[..., S:, :]).any()
+    db = {"tokens": batch["tokens"][:, :1],
+          "positions": make_positions(cfg, B, 1, start=S)}
+    if cfg.input_mode == "embeddings":
+        db["embeds"] = batch["embeds"][:, :1]
+    xla, _ = M.decode(params, cfg, db, grown)
+    with pltpu.force_tpu_interpret_mode():
+        pal, _ = M.decode(params, dataclasses.replace(cfg, use_pallas=True),
+                          db, grown)
+    rel = float(jnp.max(jnp.abs(pal - xla))) / float(jnp.max(jnp.abs(xla)))
+    assert rel < 1e-4, f"{arch}: pallas/xla decode rel={rel:.2e}"

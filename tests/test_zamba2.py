@@ -130,7 +130,7 @@ def test_generate_stamps_and_counters(setup, monkeypatch):
     assert (stamps["prefill_dispatch"] <= stamps["first_token"]
             <= stamps["last_token"])
     assert stamps["decode_steps"] == new - 1
-    cache = M.init_cache(cfg, B, S + new)
+    cache = M.init_cache(cfg, B, serve.cache_len(S + new))
     flat = jax.tree_util.tree_flatten_with_path(cache)[0]
     kv = sum(x.nbytes for p, x in flat if p[-1].key in ("k", "v"))
     ssm = sum(x.nbytes for p, x in flat if p[-1].key == "state"
