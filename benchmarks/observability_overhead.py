@@ -1,8 +1,10 @@
 """Observability overhead benchmark: what does watching the run cost?
 
-Two passes over the same seeded 1M-task null campaign (the
+Two passes per scale over the same seeded null campaign (the
 throughput_scale flux-x8 configuration, whose committed wall time in
-``BENCH_runtime.json`` is the regression baseline):
+``BENCH_runtime.json`` is the regression baseline; the largest default
+scale is the paper's Fig. 5 run at 1,024 nodes, 229,376 tasks). Every
+wall time here is a host-CPU record, not a device measurement:
 
 * **off** — campaign only, nothing derived after the drain;
 * **on**  — campaign with a gauge-only Watcher attached (trace recording is
@@ -23,10 +25,12 @@ Gates (exit nonzero on miss):
   same 10% band the campaign itself is held to;
 * with ``--stream``, the *streamed campaign* wall (full Watcher folding
   every tick) is held to the same 1.10x band;
-* post-hoc analysis (RunReport.collect) < 2s at 1M tasks.
+* post-hoc analysis (RunReport.collect) < 2s.
+
+All three gates apply at the run's largest scale.
 
 Usage:
-    PYTHONPATH=src python benchmarks/observability_overhead.py          # 10k + 1M
+    PYTHONPATH=src python benchmarks/observability_overhead.py          # 10k + 229,376
     PYTHONPATH=src python benchmarks/observability_overhead.py --quick  # CI: same
     PYTHONPATH=src python benchmarks/observability_overhead.py --scales 10000
     PYTHONPATH=src python benchmarks/observability_overhead.py --stream
@@ -41,12 +45,13 @@ import tempfile
 import time
 from typing import Dict, List, Optional
 
+from repro.core import calibration as CAL
 from repro.core.pilot import PilotDescription
 from repro.core.task import DescriptionBatch, TaskDescription
 from repro.observability import RunReport, Watcher, export_chrome_trace
 from repro.runtime import PilotManager, Session, TaskManager
 
-DEFAULT_SCALES = (10_000, 1_000_000)
+DEFAULT_SCALES = (10_000, CAL.tasks_for_nodes(1024))
 NODES = 64
 ANALYSIS_GATE_S = 2.0
 WALL_BAND = 1.10
@@ -200,25 +205,25 @@ def main(argv: Optional[List[str]] = None) -> int:
              "obs_overhead_s": round(on["wall_s"] - off["wall_s"], 3)}
         if args.stream:
             r.update(run_streamed(n, args.seed))
+        gated = n == max(scales)
         base = baseline.get((r["config"], n))
         if base is not None:
             r["runtime_baseline_wall_s"] = base
-            if (not args.no_regress_check and n >= 1_000_000
+            if (not args.no_regress_check and gated
                     and r["campaign_wall_s"] > WALL_BAND * base):
                 failures.append(
                     f"observed campaign wall at n={n:,}: "
                     f"{r['campaign_wall_s']:.2f}s exceeds "
                     f"{WALL_BAND:.0%} of the committed runtime baseline "
                     f"{base:.2f}s")
-            if (args.stream and not args.no_regress_check
-                    and n >= 1_000_000
+            if (args.stream and not args.no_regress_check and gated
                     and r["stream_campaign_wall_s"] > WALL_BAND * base):
                 failures.append(
                     f"streamed campaign wall at n={n:,}: "
                     f"{r['stream_campaign_wall_s']:.2f}s exceeds "
                     f"{WALL_BAND:.0%} of the committed runtime baseline "
                     f"{base:.2f}s")
-        if n >= 1_000_000 and r["analysis_wall_s"] > ANALYSIS_GATE_S:
+        if gated and r["analysis_wall_s"] > ANALYSIS_GATE_S:
             failures.append(
                 f"analysis at n={n:,} took {r['analysis_wall_s']:.2f}s "
                 f"(gate {ANALYSIS_GATE_S:.1f}s)")
@@ -241,9 +246,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                      "with a gauge-only Watcher + RunReport.collect + capped Chrome "
                      "export; the observed campaign wall is gated to 110% "
                      "of the committed BENCH_runtime wall, post-hoc "
-                     "analysis gated to <2s at 1M; --stream adds a third "
-                     "pass with a full streaming Watcher (per-tick delta "
-                     "folds + health rules) held to the same 110% band"),
+                     "analysis gated to <2s, both at the largest scale; "
+                     "--stream adds a third pass with a full streaming "
+                     "Watcher (per-tick delta folds + health rules) held "
+                     "to the same 110% band"),
+        "clock": "host CPU wall time of the sim engine, not a device metric",
         "stream_lane": bool(args.stream),
         "nodes": NODES,
         "seed": args.seed,

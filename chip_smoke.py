@@ -328,7 +328,9 @@ def run_partitions(cfg, *, steps=3, global_batch=8, seq_len=2048, seed=0,
         alone = train_payload(cfg, steps, global_batch, seq_len, seed,
                               mesh=submesh(mesh, "data", 0, 1))
     parts = []
-    for t in tasks:
+    # report in partition order: which task a free partition picks up
+    # first is a race between the two workers
+    for t in sorted(tasks, key=lambda t: t.partition):
         r = t.result
         log(f"partition {t.partition}: devices {r['mesh_devices']}, params "
             f"on {r['param_devices']}, losses {r['losses']}")

@@ -19,14 +19,12 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import os
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
 from repro.core import calibration as CAL
-from repro.core import cohort as _cohort
 from repro.core.executors.base import BaseExecutor
 from repro.core.resources import NodeSpec
 from repro.core.task import (DescriptionBatch, DescView, Task,
@@ -165,8 +163,6 @@ class Agent:
                  speculation_factor: float = 3.0,
                  speculation_quantile: float = 0.95,
                  speculation_min_samples: int = 10,
-                 cohort: bool = True,
-                 cohort_min: int = 50_000,
                  retry_backoff: float = 0.0,
                  retry_backoff_max: float = 60.0,
                  retry_jitter: float = 0.0):
@@ -192,15 +188,6 @@ class Agent:
         # being retried/finished — the failing pilot must not advance them
         self._evacuating: Optional[List[Task]] = None
 
-        # cohort fast path (repro.core.cohort): eligible homogeneous bulks
-        # of >= cohort_min tasks are planned closed-form instead of running
-        # the object state machine; REPRO_COHORT=0 force-disables globally
-        self._cohort = cohort and os.environ.get("REPRO_COHORT", "1") != "0"
-        self._cohort_min = max(1, cohort_min)
-        self.cohorts: List[Any] = []      # planned TaskCohort columns
-        self._cohort_n = 0                # members across all cohorts
-        self._cohort_done = 0             # terminal members (event-advanced)
-
         self.tasks: Dict[str, Task] = {}
         self._dispatch_q: deque = deque()
         self._dispatch_busy = False
@@ -213,10 +200,6 @@ class Agent:
         # listeners (campaigns, service readiness watchers, ...)
         self.on_task_done: Optional[Callable[[Task], None]] = None
         self._done_callbacks: List[Callable[[Task], None]] = []
-        # parallel to _done_callbacks: each entry is a zero-arg probe
-        # declaring the callback safe to skip for cohort members (or None
-        # = never safe, which disables the cohort path while registered)
-        self._cb_cohort_safe: List[Optional[Callable[[], bool]]] = []
         self._spec_watch: Dict[str, Any] = {}
         self._spec_clones: Dict[str, Task] = {}
         # duration-free speculation (ROADMAP: RealEngine stragglers): the
@@ -268,32 +251,14 @@ class Agent:
         self.ready_at = max(ex.ready_at for ex in self.backends.values())
 
     # ---------------------------------------------------------------- submit
-    def submit(self, descriptions, cohort: Optional[bool] = None):
+    def submit(self, descriptions) -> List[Task]:
         """Submit a bulk of task descriptions — a ``List[TaskDescription]``
-        or a columnar :class:`~repro.core.task.DescriptionBatch`. Returns a
-        list of ``Task`` objects — or, when the bulk is large and
-        homogeneous enough for the vectorized cohort path (see
-        ``repro.core.cohort``), a :class:`repro.core.task.CohortWave` (same
-        iteration surface, lazy per-task views). Batches always try the
-        cohort planner (a batch is an explicit bulk, like ``submit_wave``);
-        lists only at ``cohort_min`` size. ``cohort=False`` forces the
-        object path for this call."""
+        or a columnar :class:`~repro.core.task.DescriptionBatch` — and
+        return one ``Task`` per description, in submission order."""
         with self.engine.span("rp:submit"):
-            use_cohort = self._cohort if cohort is None else (self._cohort
-                                                              and cohort)
             engine = self.engine
             if isinstance(descriptions, DescriptionBatch):
-                if use_cohort:
-                    with engine.lock:
-                        wave = _cohort.try_plan_batch(self, descriptions)
-                    if wave is not None:
-                        return wave
                 return self._submit_batch_objects(descriptions)
-            if use_cohort and len(descriptions) >= self._cohort_min:
-                with engine.lock:
-                    wave = _cohort.try_plan(self, descriptions)
-                if wave is not None:
-                    return wave
             out = []
             with engine.lock:
                 # pause cyclic GC for the bulk ingestion storm: allocating n
@@ -369,9 +334,7 @@ class Agent:
         scheduler admission keep that timestamp — their measured wait
         covers the scheduler hold, not just the dispatch queue. A
         :class:`DescriptionBatch` is accepted too: its rows enter as fresh
-        object tasks (bulk-stamped SCHEDULING now), bypassing the cohort
-        planner — prepared submission implies the caller already did
-        admission."""
+        object tasks (bulk-stamped SCHEDULING now)."""
         if isinstance(prepared, DescriptionBatch):
             return self._submit_batch_objects(prepared)
         engine = self.engine
@@ -395,14 +358,11 @@ class Agent:
                     gc.enable()
         return prepared
 
-    def submit_wave(self, template: TaskDescription, n: int):
+    def submit_wave(self, template: TaskDescription, n: int) -> List[Task]:
         """Submit ``n`` clones of ``template`` without materializing ``n``
         descriptions: the wave is one all-scalar ``DescriptionBatch``
-        (every column a shared scalar, uids a reserved block), planned
-        closed-form by the cohort planner when eligible and ingested as
-        object tasks over lazy row views otherwise — O(1) memory per task
-        at submit either way. Returns a ``CohortWave`` or a list of
-        tasks."""
+        (every column a shared scalar, uids a reserved block) ingested as
+        object tasks over lazy row views."""
         if n <= 0:
             return []
         return self.submit(DescriptionBatch.from_template(template, n))
@@ -415,7 +375,7 @@ class Agent:
         submission), with an ``agent:resubmit`` trace event carrying the
         lineage so recovery overhead is measurable per the RP
         characterization protocol."""
-        tasks = self.submit(descriptions, cohort=False)
+        tasks = self.submit(descriptions)
         self._record_resubmit(tasks, origin)
         return tasks
 
@@ -628,45 +588,15 @@ class Agent:
         if self.on_task_done:
             self.on_task_done(task)
 
-    def add_done_callback(self, cb: Callable[[Task], None],
-                          cohort_safe: Optional[Callable[[], bool]] = None):
+    def add_done_callback(self, cb: Callable[[Task], None]):
         """Register a terminal-state listener; all registered callbacks run
         (in registration order) plus the legacy ``on_task_done`` slot, so
-        campaigns and service watchers compose instead of clobbering.
-
-        Cohort members never invoke per-task callbacks, so any registered
-        callback disables the cohort fast path — unless it declares a
-        ``cohort_safe`` probe returning True when skipping it for a planned
-        wave is currently semantics-preserving (e.g. the FIFO passthrough
-        scheduler when it holds no admission/dependency state)."""
+        campaigns and service watchers compose instead of clobbering."""
         self._done_callbacks.append(cb)
-        self._cb_cohort_safe.append(cohort_safe)
 
-    # --------------------------------------------------------------- cohorts
-    def _release_cohort_dispatch(self):
-        """Planned dispatch window over: reopen the pipeline for object-path
-        submissions that queued behind the wave."""
-        self._dispatch_busy = False
-        self._pump_dispatch()
-
-    def _cohort_chunk_done(self, cohort, ex: BaseExecutor, k: int,
-                           final: bool):
-        """Bucketed completion accounting for a planned cohort: one event
-        advances ``k`` members to terminal (vs one event per task on the
-        object path)."""
-        cohort.n_terminal += k
-        self._cohort_done += k
-        ex.stats["completed"] += k
-        if final:
-            cohort.finalized = True
-
-    def all_tasks(self) -> List[Any]:
-        """Everything submitted, for analytics: object ``Task`` instances
-        plus planned ``TaskCohort`` columns (``repro.core.analytics``
-        consumes both)."""
-        out: List[Any] = list(self.tasks.values())
-        out.extend(self.cohorts)
-        return out
+    def all_tasks(self) -> List[Task]:
+        """Everything submitted, for analytics."""
+        return list(self.tasks.values())
 
     # ----------------------------------------------------------- speculation
     def _quantile_deadline(self) -> Optional[float]:
@@ -736,11 +666,8 @@ class Agent:
         pilot performs no retries of its own (the ``_evacuating`` intercept
         swallows the on_failure storm from the executor kills).
 
-        Unsupported shapes fail loudly rather than silently losing work:
-        a mid-flight cohort wave has no per-task objects to evacuate, and
-        service replicas belong to their owning ``Service`` fault model."""
-        if any(not c.finalized for c in self.cohorts):
-            raise RuntimeError("cannot evacuate a pilot mid-cohort-wave")
+        Service replicas fail loudly rather than silently losing work:
+        they belong to their owning ``Service`` fault model."""
         for ex in self.backends.values():
             for t in ex.running_tasks():
                 if t.description.kind == "service":
@@ -788,23 +715,19 @@ class Agent:
     def n_unfinished(self) -> int:
         """Tasks not yet in a terminal state — O(1) via the terminal
         counters (the drain predicate runs once per engine wakeup)."""
-        return (len(self.tasks) + self._cohort_n
-                - self._n_terminal - self._cohort_done)
+        return len(self.tasks) - self._n_terminal
 
     def run_until_complete(self, max_events: int = 50_000_000,
                            timeout: Optional[float] = None) -> float:
         # O(1) predicate via the terminal counters (the old per-wakeup task
         # list-scan made real-engine drains O(n^2) end-to-end)
-        self.engine.drain(lambda: (self._n_terminal >= len(self.tasks)
-                                   and self._cohort_done >= self._cohort_n),
+        self.engine.drain(lambda: self._n_terminal >= len(self.tasks),
                           timeout=timeout, max_events=max_events)
         with self.engine.lock:
             unfinished = self._unfinished()
-            stuck_cohorts = [c for c in self.cohorts if not c.finalized]
-        if unfinished or stuck_cohorts:
+        if unfinished:
             raise RuntimeError(
-                f"run drained with {len(unfinished)} unfinished tasks and "
-                f"{len(stuck_cohorts)} unfinalized cohorts")
+                f"run drained with {len(unfinished)} unfinished tasks")
         return self.engine.now()
 
     @property

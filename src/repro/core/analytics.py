@@ -21,25 +21,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from repro.core.calibration import CORES_PER_NODE
-from repro.core.task import CohortWave, Task, TaskCohort, TaskState
-
-
-def _split_cohorts(tasks: Sequence) -> tuple:
-    """Partition an analytics input into object tasks and TaskCohort
-    columns (CohortWaves unpack to their groups). Anything task-shaped
-    stays in the object list."""
-    objs: List[Task] = []
-    cohorts: List[TaskCohort] = []
-    for item in tasks:
-        if isinstance(item, Task):
-            objs.append(item)
-        elif isinstance(item, TaskCohort):
-            cohorts.append(item)
-        elif isinstance(item, CohortWave):
-            cohorts.extend(item.cohorts)
-        else:
-            objs.append(item)
-    return objs, cohorts
+from repro.core.task import Task, TaskState
 
 
 @dataclass
@@ -70,17 +52,21 @@ def compute_metrics(tasks: Sequence[Task], total_cores: int,
     be the worker count, busy-time is charged one worker per task, and the
     makespan extends to the last *terminal* event (failures included)."""
     real = mode == "real"
-    objs, cohorts = _split_cohorts(tasks)
-    n_total = len(objs) + sum(c.n for c in cohorts)
     n_failed = 0
     term_end = 0.0
     starts_raw: List[float] = []
     ends_raw: List[float] = []
     cores_raw: List[int] = []
-    for t in objs:                        # single pass: extract columns
+    n_total = 0
+    first_sched = float("inf")
+    for t in tasks:                       # single pass: extract columns
+        n_total += 1
+        ts = t.timestamps
+        sched = ts.get("SCHEDULING", 0.0)
+        if sched < first_sched:
+            first_sched = sched
         state = t.state
         if state is TaskState.DONE:
-            ts = t.timestamps
             starts_raw.append(ts.get("RUNNING", 0.0))
             ends_raw.append(ts["DONE"])
             if real:
@@ -92,42 +78,23 @@ def compute_metrics(tasks: Sequence[Task], total_cores: int,
         elif state is TaskState.FAILED:
             n_failed += 1
             if real:
-                term_end = max(term_end, t.timestamps.get("FAILED", 0.0))
+                term_end = max(term_end, ts.get("FAILED", 0.0))
         elif real and state in (TaskState.STOPPED, TaskState.CANCELED):
-            term_end = max(term_end, t.timestamps.get(state.value, 0.0))
-    # cohort columns feed in directly: members never fail, and their
-    # completion times are fully determined at plan time
-    starts_arrays = ([np.asarray(starts_raw)] if starts_raw else [])
-    ends_arrays = ([np.asarray(ends_raw)] if ends_raw else [])
-    cores_arrays = ([np.asarray(cores_raw)] if cores_raw else [])
-    for c in cohorts:
-        if c.run_t is None:
-            continue
-        starts_arrays.append(c.run_t)
-        ends_arrays.append(c.done_t)
-        cores_arrays.append(np.full(c.n, 1 if real else c.cores_per_task(),
-                                    dtype=np.int64))
-    n_done = sum(len(a) for a in starts_arrays)
+            term_end = max(term_end, ts.get(state.value, 0.0))
+    n_done = len(starts_raw)
     if not n_done:
         return RunMetrics(n_total, 0, n_failed, 0.0, 0.0, 0.0, 0.0,
                           0.0, 0)
 
-    starts_unsorted = (starts_arrays[0] if len(starts_arrays) == 1
-                       else np.concatenate(starts_arrays))
-    ends = (ends_arrays[0] if len(ends_arrays) == 1
-            else np.concatenate(ends_arrays))
-    cores_col = (cores_arrays[0] if len(cores_arrays) == 1
-                 else np.concatenate(cores_arrays))
+    starts_unsorted = np.asarray(starts_raw)
+    ends = np.asarray(ends_raw)
+    cores_col = np.asarray(cores_raw)
     starts = np.sort(starts_unsorted)
 
     if t_submit0 is not None:
         t0 = t_submit0
     else:
-        t0 = min((t.timestamps.get("SCHEDULING", 0.0) for t in objs),
-                 default=float("inf"))
-        for c in cohorts:
-            if c.sched_t < t0:
-                t0 = c.sched_t
+        t0 = first_sched
     start_min = float(starts[0])
     start_max = float(starts[-1])
     end_max = float(ends.max())
@@ -172,11 +139,10 @@ def occupancy_utilization(tasks: Sequence[Task], total_cores: int) -> float:
     meaningful for zero-duration calibration waves (the paper's §4 null
     workloads), where execution busy-time is identically zero while the
     launch pipeline keeps every allocation occupied for its service time."""
-    objs, cohorts = _split_cohorts(tasks)
     starts_raw: List[float] = []
     ends_raw: List[float] = []
     cores_raw: List[int] = []
-    for t in objs:
+    for t in tasks:
         if t.state is not TaskState.DONE:
             continue
         ts = t.timestamps
@@ -187,20 +153,11 @@ def occupancy_utilization(tasks: Sequence[Task], total_cores: int) -> float:
         d = t.description
         cores_raw.append(d.nodes * CORES_PER_NODE if d.nodes
                          else max(1, d.cores))
-    starts_arrays = ([np.asarray(starts_raw)] if starts_raw else [])
-    ends_arrays = ([np.asarray(ends_raw)] if ends_raw else [])
-    cores_arrays = ([np.asarray(cores_raw)] if cores_raw else [])
-    for c in cohorts:
-        if c.launch_t is None:
-            continue
-        starts_arrays.append(c.launch_t)
-        ends_arrays.append(c.done_t)
-        cores_arrays.append(np.full(c.n, c.cores_per_task(), dtype=np.int64))
-    if not starts_arrays:
+    if not starts_raw:
         return 0.0
-    starts = np.concatenate(starts_arrays)
-    ends = np.concatenate(ends_arrays)
-    cores = np.concatenate(cores_arrays)
+    starts = np.asarray(starts_raw)
+    ends = np.asarray(ends_raw)
+    cores = np.asarray(cores_raw)
     window = float(ends.max() - starts.min())
     if window <= 0.0 or total_cores <= 0:
         return 0.0
@@ -211,27 +168,17 @@ def occupancy_utilization(tasks: Sequence[Task], total_cores: int) -> float:
 def concurrency_series(tasks: Sequence[Task], dt: float = 10.0
                        ) -> List[tuple]:
     """(t, #running) samples — the paper's Fig. 4/8 green curves."""
-    objs, cohorts = _split_cohorts(tasks)
     starts_raw: List[float] = []
     ends_raw: List[float] = []
-    for t in objs:
+    for t in tasks:
         ts = t.timestamps
         if "RUNNING" in ts and ("DONE" in ts or "FAILED" in ts):
             starts_raw.append(ts["RUNNING"])
             ends_raw.append(ts.get("DONE", ts.get("FAILED")))
-    starts_arrays = ([np.asarray(starts_raw)] if starts_raw else [])
-    ends_arrays = ([np.asarray(ends_raw)] if ends_raw else [])
-    for c in cohorts:
-        if c.run_t is None:
-            continue
-        starts_arrays.append(c.run_t)
-        ends_arrays.append(c.done_t)
-    if not starts_arrays:
+    if not starts_raw:
         return []
-    starts_sorted = np.sort(starts_arrays[0] if len(starts_arrays) == 1
-                            else np.concatenate(starts_arrays))
-    ends_sorted = np.sort(ends_arrays[0] if len(ends_arrays) == 1
-                          else np.concatenate(ends_arrays))
+    starts_sorted = np.sort(np.asarray(starts_raw))
+    ends_sorted = np.sort(np.asarray(ends_raw))
     # every end is >= its start, so the trace's last event is the last end
     t_last = float(ends_sorted[-1])
 
@@ -289,7 +236,8 @@ class SchedMetrics:
                 "fairness": self.fairness}
 
 
-def _desc_class(d, by: str) -> str:
+def _task_class(t: Task, by: str) -> str:
+    d = t.description
     if by == "tenant":
         return d.tenant or "default"
     if by == "priority":
@@ -299,37 +247,22 @@ def _desc_class(d, by: str) -> str:
     raise KeyError(f"unknown class key {by!r} (tenant|priority|stage)")
 
 
-def _task_class(t: Task, by: str) -> str:
-    return _desc_class(t.description, by)
-
-
 def sched_metrics(tasks: Sequence[Task], by: str = "tenant"
                   ) -> SchedMetrics:
     """Scheduling-quality metrics per class: wait percentiles (admission to
     start — scheduler hold plus dispatch plus backend queueing) and the
     Jain fairness index over weighted served work, the quantity a
     fair-share policy equalizes. Services count PROVISIONING as their
-    start; tasks that never started contribute to ``n`` only.
-
-    Cohort-aware: ``TaskCohort``/``CohortWave`` inputs contribute their
-    plan-time columns directly (waits = ``run_t - sched_t``, served from
-    ``done_t - run_t`` times the member width), so gated-scheduler runs at
-    cohort scale report fairness too instead of silently dropping the
-    cohort members."""
-    objs, cohorts = _split_cohorts(tasks)
+    start; tasks that never started contribute to ``n`` only."""
     groups: Dict[str, List[Task]] = {}
-    for t in objs:
+    for t in tasks:
         groups.setdefault(_task_class(t, by), []).append(t)
-    coh_groups: Dict[str, List[TaskCohort]] = {}
-    for c in cohorts:
-        coh_groups.setdefault(_desc_class(c.template, by), []).append(c)
     by_class: Dict[str, ClassWait] = {}
     shares: List[float] = []
-    for cls in sorted(set(groups) | set(coh_groups)):
-        ts = groups.get(cls, ())
+    for cls in sorted(groups):
+        ts = groups[cls]
         n_cls = len(ts)
         waits: List[float] = []
-        wait_parts: List[np.ndarray] = []
         served = 0.0
         weight = 0.0
         for t in ts:
@@ -346,17 +279,7 @@ def sched_metrics(tasks: Sequence[Task], by: str = "tenant"
                          else max(1, d.cores))
                 served += width * (end - start)
         if waits:
-            wait_parts.append(np.asarray(waits))
-        for c in coh_groups.get(cls, ()):
-            n_cls += c.n
-            weight = max(weight, c.template.share)
-            if c.run_t is None:
-                continue
-            wait_parts.append(c.run_t - c.sched_t)
-            served += c.cores_per_task() * float((c.done_t - c.run_t).sum())
-        if wait_parts:
-            w = (wait_parts[0] if len(wait_parts) == 1
-                 else np.concatenate(wait_parts))
+            w = np.asarray(waits)
             p50, p99 = np.percentile(w, (50.0, 99.0))
             by_class[cls] = ClassWait(n_cls, len(w), float(w.mean()),
                                       float(p50), float(p99),

@@ -171,7 +171,7 @@ class Campaign:
     def _stage_uids(tasks) -> List[str]:
         """Uids of one submitted stage, whatever shape the submission
         returned: a task list, a columnar batch handle (uids come from the
-        batch — materialization state is irrelevant), or a cohort wave."""
+        batch — materialization state is irrelevant)."""
         batch = getattr(tasks, "batch", None)
         if batch is not None:
             return [batch.uid(i) for i in range(batch.n)]

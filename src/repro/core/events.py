@@ -12,11 +12,10 @@ dataclass plus an eager by-name index insert.
 
 Storage is a pair of preallocated numpy buffers grown geometrically (plus a
 row counter), so bulk appends (``record_fast_many``) are two slice
-assignments — ~40ms for 10M rows where the previous ``array.frombytes``
-path paid a tobytes copy per column — and reads are zero-copy slice views
-instead of ``np.frombuffer`` over an exported buffer. Writers that know a
-bulk append is coming can call ``reserve_rows`` first to size the buffers
-exactly and avoid transient doubling spikes at the 10M-task tier.
+assignments and reads are zero-copy slice views instead of
+``np.frombuffer`` over an exported buffer. Writers that know a bulk append
+is coming can call ``reserve_rows`` first to size the buffers exactly and
+avoid transient doubling spikes.
 
 ``record`` interns its strings per call; state machines on the hot path use
 ``entity_id`` once per entity plus ``record_fast`` per event to skip even
@@ -74,7 +73,7 @@ class Profiler:
         self._entity_ids: Dict[str, int] = {}
         self._name_ids: Dict[str, int] = {}
         self._next_eid = 0
-        # lazily-named entity blocks (cohort waves): (base, count, name_fn),
+        # lazily-named entity blocks (batch rows): (base, count, name_fn),
         # sorted by base — entity_of resolves ids in a block through name_fn
         # without ever materializing the block's id->string map
         self._entity_blocks: List[tuple] = []
@@ -103,8 +102,8 @@ class Profiler:
                          name_fn: Callable[[int], str]) -> int:
         """Reserve ``count`` consecutive entity ids whose names resolve
         lazily: id ``base + i`` maps to ``name_fn(i)``. Nothing per entity
-        is stored — cohort waves use this so a 10M-task trace does not
-        intern 10M uid strings."""
+        is stored — batch submission uses this so its bulk SCHEDULING
+        stamp interns no uid strings."""
         base = self._next_eid
         self._next_eid = base + count
         self._entity_blocks.append((base, count, name_fn))
@@ -135,10 +134,9 @@ class Profiler:
 
     def reserve_rows(self, extra: int) -> None:
         """Ensure capacity for ``extra`` more rows in one allocation. Bulk
-        writers (cohort trace stamping) call this before a known-size run of
+        writers (batch submission) call this before a known-size run of
         appends so the buffers are sized exactly once instead of doubling
-        through it — at 10M tasks that is the difference between an 800MB
-        column and a transient 1.6GB spike."""
+        through it."""
         need = self._n + extra
         if need > len(self._times):
             self._grow(need)
@@ -158,7 +156,7 @@ class Profiler:
         ``times`` (float array-like) and ``eids`` (int array-like) must have
         equal length; ``nid`` is one name id for the whole batch or an
         array of per-event name ids (same length). Equivalent to a loop of
-        ``record_fast`` (golden-pinned in tests/test_cohort_golden.py) but
+        ``record_fast`` (golden-pinned in tests/test_profiler.py) but
         two slice assignments regardless of batch size."""
         times = np.ascontiguousarray(times, dtype=np.float64)
         eids = np.ascontiguousarray(eids, dtype=np.int64)

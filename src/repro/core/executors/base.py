@@ -225,10 +225,6 @@ class SimLaunchServer:
         # assigned but not yet in ``running`` — kill()/fail_node() must
         # cover this limbo window or its resources leak
         self._launching: Optional[Task] = None
-        # while a planned cohort wave (repro.core.cohort) occupies this
-        # server, pump() is a no-op until the wave's planned end time — an
-        # event resets this to 0.0 and re-pumps
-        self._cohort_until = 0.0
         self.running: Dict[str, Task] = {}
         self.on_complete: Optional[Callable[[Task], None]] = None
         self.on_failure: Optional[Callable[[Task, str], None]] = None
@@ -259,7 +255,7 @@ class SimLaunchServer:
             self._stall_head = None        # pool changed: rescan
 
     def pump(self):
-        if self.busy or self.dead or self._cohort_until:
+        if self.busy or self.dead:
             return
         # a sibling server (shared backlog) may have launched — or the agent
         # canceled — the gang this claim was draining nodes for: release it

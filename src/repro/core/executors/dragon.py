@@ -114,17 +114,6 @@ class SimDragonExecutor(BaseExecutor):
         if self.on_failure:
             self.on_failure(task, err)
 
-    def cohort_model(self, kind: str) -> dict:
-        """Launch-race parameters for the cohort planner (repro.core.cohort):
-        instances in pump order, per-instance mean launch service time for
-        ``kind`` (the same ``1.0 / dragon_rate`` float the per-task service
-        closure computes), the lognormal sigma, and the shared limiter."""
-        return {"instances": self.instances,
-                "means": [1.0 / CAL.dragon_rate(i.pool.n_nodes, kind)
-                          for i in self.instances],
-                "sigma": 0.15,
-                "coord": self.coord}
-
     def nominal_rate(self, kind: str = "function") -> float:
         per = CAL.dragon_rate(self.n_nodes // self.n_partitions, kind)
         return min(per * self.n_partitions,

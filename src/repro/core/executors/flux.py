@@ -189,17 +189,6 @@ class SimFluxExecutor(BaseExecutor):
         inst = sum(CAL.flux_instance_rate(i.pool.n_nodes) for i in live)
         return min(inst, CAL.rp_coord_rate(self.n_nodes, len(self.instances)))
 
-    def cohort_model(self, kind: str) -> dict:
-        """Launch-race parameters for the cohort planner (repro.core.cohort):
-        live instances in pump order, the per-instance mean launch service
-        time (same float expression the per-task closure evaluates), the
-        lognormal sigma, and the shared coordination limiter."""
-        return {"instances": self._live,
-                "means": [1.0 / CAL.flux_instance_rate(i.pool.n_nodes)
-                          for i in self._live],
-                "sigma": CAL.FLUX_RATE_SIGMA,
-                "coord": self.coord}
-
     @property
     def total_cores(self) -> int:
         return self.n_nodes * self.spec.cores

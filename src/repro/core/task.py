@@ -85,7 +85,8 @@ def reserve_uid_block(count: int, prefix: str = "task") -> tuple:
     """Reserve ``count`` consecutive uids from the global counter without
     materializing the strings; returns ``(prefix, start)`` so member ``i``
     is ``"%s.%06d" % (prefix, start + i)`` — the exact ``new_uid`` format.
-    Cohort waves use this to name 10M tasks in O(1) memory."""
+    Template batches (``DescriptionBatch.from_template``) name their rows
+    this way in O(1) memory."""
     global _uid_counter
     start = next(_uid_counter)
     _uid_counter = itertools.count(start + count)
@@ -492,12 +493,6 @@ class DescriptionBatch:
             return v
         return np.zeros(self.n, dtype=np.int64), [v]
 
-    def sparse_rows(self, name: str) -> Dict[int, Any]:
-        """The per-row override dict for a rare field (empty when the field
-        is uniform/default)."""
-        v = self._sparse.get(name)
-        return v if isinstance(v, _SparseCol) else {}
-
     def has_field(self, name: str) -> bool:
         """Whether any row carries a non-default value for ``name`` (rare
         fields: conservative — presence of the column counts)."""
@@ -665,212 +660,3 @@ class Task:
 
     def __repr__(self):
         return f"<Task {self.uid} {self.state.value} backend={self.backend}>"
-
-
-# ---------------------------------------------------------------------------
-# Cohort execution path (struct-of-arrays waves) — see repro.core.cohort for
-# the planner that fills these columns and docs/eligibility rules in
-# src/repro/runtime/README.md.
-# ---------------------------------------------------------------------------
-
-class TaskCohort:
-    """Columnar representation of one homogeneous group of a task wave:
-    every per-task quantity the object path would scatter across ``Task``
-    instances lives in a numpy column (one float64 array per transition
-    timestamp). All members share one route/backend and one resource shape;
-    durations may vary per task. Individual members materialize lazily as
-    :class:`CohortTaskView` (task-shaped, read-only) via ``task(i)``."""
-
-    __slots__ = ("engine", "n", "template", "descs", "backend",
-                 "uid_prefix", "uid_start", "sched_t", "queued_t",
-                 "launch_t", "run_t", "done_t", "durations", "n_terminal",
-                 "finalized", "rows", "src_batch")
-
-    def __init__(self, engine, template: TaskDescription, n: int,
-                 backend: str, descs: Optional[List[TaskDescription]] = None,
-                 uid_prefix: str = "task", uid_start: int = 0,
-                 rows=None, src_batch=None):
-        self.engine = engine
-        self.n = n
-        self.template = template          # shape/kind source for analytics
-        self.descs = descs                # per-member descriptions, or None
-        self.backend = backend            # (wave API: template + uid block)
-        self.uid_prefix = uid_prefix
-        self.uid_start = uid_start
-        self.rows = rows                  # member -> source-batch row, or
-        self.src_batch = src_batch        # None (member i IS row i)
-        self.sched_t = 0.0                # scalar: whole bulk stamped at once
-        self.queued_t = None              # float64[n], filled by the planner
-        self.launch_t = None
-        self.run_t = None
-        self.done_t = None
-        self.durations = None             # None (all template.duration) or
-        self.n_terminal = 0               # float64[n] per-member durations
-        self.finalized = False
-
-    # --------------------------------------------------------------- members
-    def uid(self, i: int) -> str:
-        if self.descs is not None:
-            return self.descs[i].uid
-        if self.src_batch is not None:
-            return self.src_batch.uid(
-                i if self.rows is None else int(self.rows[i]))
-        return "%s.%06d" % (self.uid_prefix, self.uid_start + i)
-
-    def description(self, i: int) -> TaskDescription:
-        if self.descs is not None:
-            return self.descs[i]
-        if self.src_batch is not None:
-            return self.src_batch.view(
-                i if self.rows is None else int(self.rows[i]))
-        return self.template
-
-    def task(self, i: int) -> "CohortTaskView":
-        return CohortTaskView(self, i)
-
-    def member_done(self, i: int) -> bool:
-        return self.finalized or (self.done_t is not None
-                                  and self.done_t[i] <= self.engine.now())
-
-    @property
-    def done(self) -> bool:
-        return self.finalized
-
-    def cores_per_task(self) -> int:
-        d = self.template
-        return max(1, d.cores)            # nodes==0 is a cohort precondition
-
-    def timestamp_columns(self) -> Dict[str, Any]:
-        """Whole-cohort transition timestamps as float64 columns, keyed by
-        the same state names as ``Task.timestamps`` — the zero-copy surface
-        the lifecycle decomposer consumes (SCHEDULING, a scalar bulk stamp,
-        is broadcast; unplanned transitions are omitted)."""
-        import numpy as np
-        out: Dict[str, Any] = {
-            "SCHEDULING": np.full(self.n, self.sched_t)}
-        for key, col in (("QUEUED", self.queued_t),
-                         ("LAUNCHING", self.launch_t),
-                         ("RUNNING", self.run_t),
-                         ("DONE", self.done_t)):
-            if col is not None:
-                out[key] = col
-        return out
-
-    def __len__(self) -> int:
-        return self.n
-
-    def __iter__(self):
-        return (CohortTaskView(self, i) for i in range(self.n))
-
-    def __repr__(self):
-        return (f"<TaskCohort n={self.n} backend={self.backend} "
-                f"done={self.n_terminal}/{self.n}>")
-
-
-class CohortTaskView:
-    """Read-only, task-shaped view of one cohort member, materialized on
-    demand (``tm.wait`` predicates, analytics fallbacks, user inspection).
-    State is derived from the precomputed transition times against the
-    engine clock; after cohort finalization every member is DONE."""
-
-    __slots__ = ("_cohort", "_i")
-
-    def __init__(self, cohort: TaskCohort, i: int):
-        self._cohort = cohort
-        self._i = i
-
-    @property
-    def uid(self) -> str:
-        return self._cohort.uid(self._i)
-
-    @property
-    def description(self) -> TaskDescription:
-        return self._cohort.description(self._i)
-
-    @property
-    def backend(self) -> str:
-        return self._cohort.backend
-
-    @property
-    def state(self) -> TaskState:
-        c, i = self._cohort, self._i
-        if c.finalized:
-            return TaskState.DONE
-        now = c.engine.now()
-        if c.done_t is not None and c.done_t[i] <= now:
-            return TaskState.DONE
-        if c.run_t is not None and c.run_t[i] <= now:
-            return TaskState.RUNNING
-        if c.launch_t is not None and c.launch_t[i] <= now:
-            return TaskState.LAUNCHING
-        if c.queued_t is not None and c.queued_t[i] <= now:
-            return TaskState.QUEUED
-        return TaskState.SCHEDULING
-
-    @property
-    def done(self) -> bool:
-        return self._cohort.member_done(self._i)
-
-    @property
-    def timestamps(self) -> Dict[str, float]:
-        c, i = self._cohort, self._i
-        ts = {"SCHEDULING": c.sched_t}
-        if c.queued_t is not None:
-            ts["QUEUED"] = float(c.queued_t[i])
-        if c.launch_t is not None:
-            ts["LAUNCHING"] = float(c.launch_t[i])
-        if c.run_t is not None:
-            ts["RUNNING"] = float(c.run_t[i])
-        if c.done_t is not None:
-            ts["DONE"] = float(c.done_t[i])
-        return ts
-
-    # object-path compatibility surface
-    result = None
-    error = None
-    retries = 0
-    partition = None
-    allocation = None
-    speculative_of = None
-    progress = 0.0
-    attempt = 0
-
-    def __repr__(self):
-        return (f"<CohortTaskView {self.uid} {self.state.value} "
-                f"backend={self.backend}>")
-
-
-class CohortWave:
-    """The result of a cohort-path bulk submission: one or more
-    :class:`TaskCohort` groups (one per route/shape) covering the whole
-    wave. Iteration yields task views group by group (cheap, lazy);
-    ``done`` is terminal-ness of the entire wave."""
-
-    __slots__ = ("cohorts", "n")
-
-    def __init__(self, cohorts: List[TaskCohort]):
-        self.cohorts = cohorts
-        self.n = sum(c.n for c in cohorts)
-
-    @property
-    def done(self) -> bool:
-        return all(c.finalized for c in self.cohorts)
-
-    def __len__(self) -> int:
-        return self.n
-
-    def __iter__(self):
-        for c in self.cohorts:
-            yield from c
-
-    def __getitem__(self, i: int):
-        if i < 0:
-            i += self.n
-        for c in self.cohorts:
-            if i < c.n:
-                return c.task(i)
-            i -= c.n
-        raise IndexError("CohortWave index out of range")
-
-    def __repr__(self):
-        return f"<CohortWave n={self.n} groups={len(self.cohorts)}>"

@@ -32,7 +32,6 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from repro.core.analytics import _split_cohorts
 from repro.core.task import TaskState
 
 from repro.observability.timeseries import (Series, occupancy,
@@ -43,14 +42,12 @@ _US = 1e6                     # seconds -> microseconds
 
 def _slice_segments(tasks: Sequence, services: Sequence = ()) -> List[tuple]:
     """Completed-task slices as ``(process, starts, ends, label_fn)``
-    segments — one per object-task backend plus one per cohort, plus one
-    per service (completed request spans). Labels resolve lazily per
-    local index, so a 1M-task wave never materializes uid strings (or a
-    1M-element object array of backend names) for slices the
-    ``max_slices`` cap will drop."""
-    objs, cohorts = _split_cohorts(tasks)
+    segments — one per task backend plus one per service (completed
+    request spans). Labels resolve lazily per local index, so request
+    ids are never formatted for slices the ``max_slices`` cap will
+    drop."""
     per_backend: Dict[str, List[List[Any]]] = {}
-    for t in objs:
+    for t in tasks:
         if t.state is not TaskState.DONE:
             continue
         ts = t.timestamps
@@ -64,11 +61,6 @@ def _slice_segments(tasks: Sequence, services: Sequence = ()) -> List[tuple]:
     segments: List[tuple] = []
     for b, (ss, ee, uu) in sorted(per_backend.items()):
         segments.append((b, np.asarray(ss), np.asarray(ee), uu.__getitem__))
-    for c in cohorts:
-        if c.run_t is None or c.done_t is None:
-            continue
-        segments.append((c.backend or "-", np.asarray(c.run_t),
-                         np.asarray(c.done_t), c.uid))
     for svc in services:
         log = svc.request_log()
         submit = np.asarray(log["submit"], dtype=np.float64)

@@ -5,9 +5,7 @@ decompositions — RADICAL-Analytics-style attribution of every task's
 submit->done span to the runtime component that held it.  This module
 derives the same decomposition closed-form from the transition timestamps
 plus the scheduler's per-task release rows, with no per-event iteration:
-object tasks contribute one extraction pass, cohort columns feed in as
-numpy arrays directly (``TaskCohort.timestamp_columns``), so million-task
-runs decompose in milliseconds.
+one extraction pass over the tasks, then numpy over the columns.
 
 Phases tile the ``SCHEDULING -> DONE`` span exactly (telescoping sums, so
 per-task phase durations reconcile with ``compute_metrics`` makespan to
@@ -37,7 +35,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.analytics import _split_cohorts
 from repro.core.calibration import CORES_PER_NODE
 from repro.core.task import TaskState
 
@@ -155,31 +152,23 @@ def lifecycle_breakdown(tasks: Sequence, profiler=None,
     """Decompose every completed task's lifecycle into the five phases and
     aggregate mean/p50/p99/max/sum per group (see module docs).
 
-    ``tasks`` is anything ``Agent.all_tasks`` returns — object ``Task``
-    instances, ``TaskCohort`` columns, ``CohortWave`` handles, mixed.
+    ``tasks`` is what ``Agent.all_tasks`` returns: ``Task`` instances.
     ``profiler`` enables scheduler-hold attribution and pilot grouping
     (without it, holds fold into ``dispatch`` and every task's pilot is
     unattributed). ``services`` adds per-service request phase splits
     (:func:`service_request_breakdown`) under ``services``."""
     if by is not None and by not in _GROUP_KEYS:
         raise KeyError(f"unknown group key {by!r} (one of {_GROUP_KEYS})")
-    objs, cohorts = _split_cohorts(tasks)
-
     rel_t: Dict[int, float] = {}
     rel_p: Dict[int, int] = {}
     if profiler is not None:
         rel_t, rel_p = _release_map(profiler)
 
-    sched_cols: List[np.ndarray] = []
-    rel_cols: List[np.ndarray] = []
-    queued_cols: List[np.ndarray] = []
-    launch_cols: List[np.ndarray] = []
-    run_cols: List[np.ndarray] = []
-    done_cols: List[np.ndarray] = []
-    cores_cols: List[np.ndarray] = []
-    label_cols: List[np.ndarray] = []     # int codes — a million-member
+    raw: List[Tuple[float, float, float, float, float, float]] = []
+    labels: List[int] = []                # int codes — a million-member
     label_names: List[str] = []           # object array would dominate agg
     label_codes: Dict[str, int] = {}
+    cores_raw: List[int] = []
     n_skipped = 0
 
     def code(lbl: str) -> int:
@@ -189,102 +178,52 @@ def lifecycle_breakdown(tasks: Sequence, profiler=None,
             label_names.append(lbl)
         return c
 
-    # ------------------------------------------------------- object tasks
-    if objs:
-        raw: List[Tuple[float, float, float, float, float, float]] = []
-        labels: List[int] = []
-        for t in objs:
-            if t.state is not TaskState.DONE:
-                n_skipped += 1
-                continue
-            ts = t.timestamps
-            try:
-                sched = ts["SCHEDULING"]
-                queued = ts["QUEUED"]
-                launch = ts["LAUNCHING"]
-                run = ts["RUNNING"]
-                done = ts["DONE"]
-            except KeyError:
-                n_skipped += 1
-                continue
-            eid = (t._trace_eid
-                   if getattr(t, "_trace_prof", None) is profiler else None)
-            release = rel_t.get(eid, sched) if eid is not None else sched
-            # a retried task's final-attempt stamps can precede the (first)
-            # release row; clamp so the tiling stays monotonic
-            release = min(max(release, sched), queued)
-            raw.append((sched, release, queued, launch, run, done))
-            if by == "backend":
-                labels.append(code(t.backend or "-"))
-            elif by == "pilot":
-                p = rel_p.get(eid) if eid is not None else None
-                labels.append(code(f"p{p}" if p is not None else "-"))
-            elif by == "tenant":
-                labels.append(code(t.description.tenant or "default"))
-            elif by == "stage":
-                labels.append(code(t.description.stage or "default"))
-            else:
-                labels.append(code("all"))
-            cores_cols.append(np.asarray([_cores_of(t.description)]))
-        if raw:
-            cols = np.asarray(raw, dtype=np.float64)
-            sched_cols.append(cols[:, 0])
-            rel_cols.append(cols[:, 1])
-            queued_cols.append(cols[:, 2])
-            launch_cols.append(cols[:, 3])
-            run_cols.append(cols[:, 4])
-            done_cols.append(cols[:, 5])
-            label_cols.append(np.asarray(labels, dtype=np.int64))
-            # collapse the per-task single-element core arrays into one
-            cores_obj = np.fromiter(
-                (c[0] for c in cores_cols), dtype=np.int64,
-                count=len(cores_cols))
-            cores_cols = [cores_obj]
-
-    # ----------------------------------------------------- cohort columns
-    for c in cohorts:
-        tsc = c.timestamp_columns()
-        if "DONE" not in tsc or "RUNNING" not in tsc:
-            n_skipped += c.n
+    for t in tasks:
+        if t.state is not TaskState.DONE:
+            n_skipped += 1
             continue
-        sched_cols.append(np.asarray(tsc["SCHEDULING"], dtype=np.float64))
-        rel_cols.append(sched_cols[-1])      # cohorts are passthrough-only
-        queued_cols.append(np.asarray(tsc["QUEUED"], dtype=np.float64))
-        launch_cols.append(np.asarray(tsc["LAUNCHING"], dtype=np.float64))
-        run_cols.append(np.asarray(tsc["RUNNING"], dtype=np.float64))
-        done_cols.append(np.asarray(tsc["DONE"], dtype=np.float64))
-        cores_cols.append(np.full(c.n, c.cores_per_task(), dtype=np.int64))
-        d = c.template
+        ts = t.timestamps
+        try:
+            sched = ts["SCHEDULING"]
+            queued = ts["QUEUED"]
+            launch = ts["LAUNCHING"]
+            run = ts["RUNNING"]
+            done = ts["DONE"]
+        except KeyError:
+            n_skipped += 1
+            continue
+        eid = (t._trace_eid
+               if getattr(t, "_trace_prof", None) is profiler else None)
+        release = rel_t.get(eid, sched) if eid is not None else sched
+        # a retried task's final-attempt stamps can precede the (first)
+        # release row; clamp so the tiling stays monotonic
+        release = min(max(release, sched), queued)
+        raw.append((sched, release, queued, launch, run, done))
         if by == "backend":
-            lbl = c.backend or "-"
+            labels.append(code(t.backend or "-"))
         elif by == "pilot":
-            lbl = "-"
+            p = rel_p.get(eid) if eid is not None else None
+            labels.append(code(f"p{p}" if p is not None else "-"))
         elif by == "tenant":
-            lbl = d.tenant or "default"
+            labels.append(code(t.description.tenant or "default"))
         elif by == "stage":
-            lbl = d.stage or "default"
+            labels.append(code(t.description.stage or "default"))
         else:
-            lbl = "all"
-        label_cols.append(np.full(c.n, code(lbl), dtype=np.int64))
+            labels.append(code("all"))
+        cores_raw.append(_cores_of(t.description))
 
     svc_bd = {s.name: service_request_breakdown(s) for s in services}
 
-    if not done_cols:
+    if not raw:
         empty = GroupBreakdown(0, {p: PhaseStats(0, 0.0, 0.0, 0.0, 0.0, 0.0)
                                    for p in PHASES}, 0.0, 0.0)
         return LifecycleBreakdown(by, 0, n_skipped, empty, {}, svc_bd)
 
-    def cat(parts: List[np.ndarray]) -> np.ndarray:
-        return parts[0] if len(parts) == 1 else np.concatenate(parts)
-
-    sched = cat(sched_cols)
-    release = cat(rel_cols)
-    queued = cat(queued_cols)
-    launch = cat(launch_cols)
-    run = cat(run_cols)
-    done = cat(done_cols)
-    cores = cat(cores_cols)
-    labels_all = cat(label_cols)
+    cols = np.asarray(raw, dtype=np.float64)
+    sched, release, queued, launch, run, done = (cols[:, i]
+                                                 for i in range(6))
+    cores = np.asarray(cores_raw, dtype=np.int64)
+    labels_all = np.asarray(labels, dtype=np.int64)
 
     phase_cols = {
         "hold": release - sched,

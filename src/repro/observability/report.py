@@ -77,8 +77,6 @@ class RunReport:
                                                 step).as_dict()
         sched = None
         if sched_by is not None:
-            # cohort-aware: TaskCohort/CohortWave columns contribute their
-            # plan-time waits and served work alongside the object tasks
             sched = sched_metrics(tasks, by=sched_by).as_dict()
         svc = {s.name: service_metrics(s).as_dict() for s in services}
         faults = (fault_metrics(profiler).as_dict()

@@ -117,8 +117,7 @@ class TraceCursor:
     Contract: ``poll()`` returns every row appended since the previous
     ``poll()`` exactly once, in append order, at O(Δ) cost (one bounded
     copy of the two raw columns plus one mask/shift each).  Row order is
-    append order, *not* time order — the cohort fast path bulk-stamps
-    whole waves with future timestamps — so aggregators sort within each
+    append order, *not* time order, so aggregators sort within each
     delta where order matters.  Polling an appending profiler is safe on
     both engines as long as the poll runs under ``engine.lock`` (the
     Watcher's callbacks do); the profiler never mutates published rows.
@@ -159,7 +158,7 @@ _EMPTY_I = np.empty(0, dtype=np.int64)
 
 def _sorted1d(a: np.ndarray) -> np.ndarray:
     """``a`` sorted ascending — returned as-is (no copy) when already
-    sorted, which trace columns of a cohort wave always are."""
+    sorted."""
     if len(a) > 1 and bool(np.any(a[1:] < a[:-1])):
         return np.sort(a)
     return a
@@ -274,8 +273,8 @@ class StreamingLevel:
         generic :meth:`fold` samples, so the two paths are bit-identical
         on frozen values and ``level``.  Only ``peak`` coarsens: it is
         sampled at bin edges and delta boundaries rather than per event
-        (display-only).  Arrays are sorted on entry if needed; cohort
-        columns arrive sorted and skip the copy."""
+        (display-only).  Arrays are sorted on entry if needed; sorted
+        columns skip the copy."""
         ns, ne = len(starts), len(ends)
         if not ns and not ne:
             return
@@ -346,12 +345,6 @@ class StreamingBreakdown:
     clamps the release into the ``[SCHEDULING, QUEUED]`` tiling exactly
     like :func:`~repro.observability.lifecycle.lifecycle_breakdown`, and
     folds the phase durations into running n/sum/max.
-
-    Aligned path (:meth:`fold_aligned`): when the caller can prove the
-    five per-transition time arrays of one delta are column-aligned —
-    same tasks, same order, full lifecycle in-delta, no holds/releases/
-    retries, which is how the cohort fast path bulk-stamps whole waves —
-    the join is elementwise and the scatter/gather is skipped entirely.
 
     The exact per-task phase durations are retained as chunk lists, so
     ``stats(exact_quantiles=True)`` reproduces the post-hoc percentiles
@@ -424,21 +417,6 @@ class StreamingBreakdown:
         cols = {"hold": rel - s, "dispatch": q - rel, "queue": la - q,
                 "launch": ru - la, "exec": times - ru}
         self._fold_cols(cols, times - s, eids)
-
-    def fold_aligned(self, s: np.ndarray, q: np.ndarray, la: np.ndarray,
-                     ru: np.ndarray, done: np.ndarray,
-                     eids: Optional[np.ndarray] = None) -> None:
-        """Elementwise join: the five time arrays describe the same tasks
-        in the same order, each lifecycle complete within this delta and
-        untouched by holds, releases, or retries (the caller proves this
-        — see ``Watcher._fold_delta``).  No release ⇒ release clamps to
-        SCHEDULING, so ``hold`` is identically zero."""
-        n = len(done)
-        if not n:
-            return
-        cols = {"hold": np.zeros(n), "dispatch": q - s, "queue": la - q,
-                "launch": ru - la, "exec": done - ru}
-        self._fold_cols(cols, done - s, eids)
 
     def _fold_cols(self, cols: Dict[str, np.ndarray], span: np.ndarray,
                    eids: Optional[np.ndarray]) -> None:
@@ -759,7 +737,6 @@ class Watcher:
         # on the first FAILED/CANCELED row (failure-free runs never pay
         # the scatter); None means "no failure seen yet"
         self._run_flags: Optional[np.ndarray] = None
-        self._saw_retry = False
         self._hold_nid: Optional[int] = None
         self._rel_prefix: Optional[str] = None
         self._last_n_done = 0
@@ -888,10 +865,10 @@ class Watcher:
         times, nids, packed = delta.times, delta.nids, delta._packed
         n = delta.n
         # ---- segment index: rows arrive in append order, and the bulk
-        # recorders (cohort waves) append long same-name runs — slice
-        # those runs as views instead of running one full-width boolean
-        # mask per watched event name.  Fragmented deltas (object-path
-        # interleaving, many short runs) fall back to masks.
+        # recorders (batch submission, dispatch ticks) append long
+        # same-name runs — slice those runs as views instead of running
+        # one full-width boolean mask per watched event name.  Fragmented
+        # deltas (many short runs) fall back to masks.
         segs: Optional[Dict[int, List[Tuple[int, int]]]] = None
         bounds = np.flatnonzero(nids[1:] != nids[:-1]) + 1
         if len(bounds) <= max(64, n >> 4):
@@ -939,42 +916,15 @@ class Watcher:
         for nid in self._rel_nids:
             rel = merge(rel, take(nid))
         fail = merge(take(self._nid(_FAILED)), take(self._nid(_CANCELED)))
-        if fail is not None:
-            self._saw_retry = True
-        if not self._saw_retry and (
-                take(self._nid("agent:retry")) is not None
-                or take(self._nid("sched:requeue")) is not None):
-            # a re-dispatched lifecycle re-records its stamp rows; killed
-            # attempts leave FAILED rows first, but *queued* casualties
-            # (instance reroute, pilot evacuation) only leave these
-            # markers — either way first-wins stamps now matter, so the
-            # aligned elementwise join is off for the rest of the run
-            self._saw_retry = True
 
         # ---- five-phase breakdown
         bd = self.breakdown
-        aligned = (done is not None and rel is None and not self._saw_retry
-                   and sched is not None and queued is not None
-                   and launch is not None and run is not None
-                   and np.array_equal(sched[1], done[1])
-                   and np.array_equal(queued[1], done[1])
-                   and np.array_equal(launch[1], done[1])
-                   and np.array_equal(run[1], done[1]))
-        if aligned:
-            # every completed task's full lifecycle sits in this delta
-            # with all five columns in the same task order (how the
-            # cohort planner bulk-stamps a wave): join elementwise and
-            # skip the stamp scatter/gather entirely
-            bd.fold_aligned(sched[0], queued[0], launch[0], run[0],
-                            done[0], done[1])
-        else:
-            for key, part in (("sched", sched), ("queued", queued),
-                              ("launch", launch), ("run", run),
-                              ("rel", rel)):
-                if part is not None:
-                    bd.fold_stamp(key, part[0], part[1])
-            if done is not None:
-                bd.fold_done(done[0], done[1])
+        for key, part in (("sched", sched), ("queued", queued),
+                          ("launch", launch), ("run", run), ("rel", rel)):
+            if part is not None:
+                bd.fold_stamp(key, part[0], part[1])
+        if done is not None:
+            bd.fold_done(done[0], done[1])
 
         # ---- throughput + inflight/occupancy levels
         start_t = run[0] if run is not None else _EMPTY_F

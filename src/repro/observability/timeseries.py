@@ -23,7 +23,6 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from repro.core.analytics import _split_cohorts
 from repro.core.calibration import CORES_PER_NODE
 from repro.core.task import STATE_EVENTS, TaskState
 
@@ -89,13 +88,9 @@ def _step_series(name: str, starts: np.ndarray, ends: np.ndarray,
 
 def _start_end_cols(tasks: Sequence, per_backend: bool = False):
     """(starts, ends, cores, backends) columns of every completed task."""
-    objs, cohorts = _split_cohorts(tasks)
-    starts: List[np.ndarray] = []
-    ends: List[np.ndarray] = []
-    cores: List[np.ndarray] = []
-    backends: List[np.ndarray] = []
     raw = []
-    for t in objs:
+    backends = []
+    for t in tasks:
         if t.state is not TaskState.DONE:
             continue
         ts = t.timestamps
@@ -107,30 +102,12 @@ def _start_end_cols(tasks: Sequence, per_backend: bool = False):
         raw.append((run, done, c))
         if per_backend:
             backends.append(t.backend or "-")
-    if raw:
-        cols = np.asarray([(r[0], r[1], r[2]) for r in raw])
-        starts.append(cols[:, 0])
-        ends.append(cols[:, 1])
-        cores.append(cols[:, 2])
-        if per_backend:
-            backends = [np.asarray(backends, dtype=object)]
-    elif per_backend:
-        backends = []
-    for c in cohorts:
-        if c.run_t is None or c.done_t is None:
-            continue
-        starts.append(np.asarray(c.run_t, dtype=np.float64))
-        ends.append(np.asarray(c.done_t, dtype=np.float64))
-        cores.append(np.full(c.n, c.cores_per_task(), dtype=np.float64))
-        if per_backend:
-            backends.append(np.full(c.n, c.backend or "-", dtype=object))
-
-    def cat(parts):
-        if not parts:
-            return np.empty(0)
-        return parts[0] if len(parts) == 1 else np.concatenate(parts)
-
-    return cat(starts), cat(ends), cat(cores), cat(backends)
+    if not raw:
+        return np.empty(0), np.empty(0), np.empty(0), np.empty(0)
+    cols = np.asarray(raw)
+    return (cols[:, 0], cols[:, 1], cols[:, 2],
+            np.asarray(backends, dtype=object) if per_backend
+            else np.empty(0))
 
 
 # ---------------------------------------------------------------------------

@@ -157,7 +157,7 @@ class TaskManager:
         self.uid = uid or new_uid("tmgr")
         self._pilots: List[Pilot] = []
         self.tasks: Dict[str, Task] = {}
-        self._waves: List[Any] = []       # CohortWaves (columnar bulks)
+        self._batch_refs: List[Any] = []  # gated batch handles (_BatchRef)
         self._scheduler = scheduler
         session._tmgrs.append(self)
 
@@ -201,11 +201,6 @@ class TaskManager:
         # seed least-loaded bulk path; gated policies hold tasks in their
         # queue and release on placement (engine.lock is taken inside)
         tasks = self.scheduler.submit(descs)
-        if not isinstance(tasks, list):
-            # cohort fast path: the bulk stays columnar (a CohortWave) —
-            # registering a million per-uid entries would defeat it
-            self._waves.append(tasks)
-            return tasks
         for t in tasks:
             self.tasks[t.uid] = t
         return tasks[0] if single else tasks
@@ -213,10 +208,9 @@ class TaskManager:
     def submit_batch(self, batch: DescriptionBatch):
         """Submit a columnar :class:`DescriptionBatch` through the campaign
         scheduler: passthrough hands the whole batch to the least-loaded
-        pilot (cohort-planned when eligible, bulk object ingestion over
-        lazy row views otherwise); gated policies hold it as row-index
-        slices and release on placement. Returns a ``CohortWave``, a task
-        list, or the scheduler's batch handle — all waitable via
+        pilot (bulk object ingestion over lazy row views); gated policies
+        hold it as row-index slices and release on placement. Returns a
+        task list or the scheduler's batch handle — both waitable via
         ``wait_tasks``."""
         if self.session.closed:
             raise RuntimeError(f"{self.uid}: session {self.session.uid} "
@@ -225,7 +219,7 @@ class TaskManager:
             raise RuntimeError(f"{self.uid}: no pilots added")
         tasks = self.scheduler.submit(batch)
         if not isinstance(tasks, list):
-            self._waves.append(tasks)      # CohortWave or _BatchRef (.done)
+            self._batch_refs.append(tasks)
             return tasks
         for t in tasks:
             self.tasks[t.uid] = t
@@ -234,9 +228,9 @@ class TaskManager:
     def submit_wave(self, template: TaskDescription, n: int):
         """Bulk-submit ``n`` clones of ``template`` as one all-scalar
         :class:`DescriptionBatch` (columnar, O(1) memory per task at
-        submit), preferring the cohort fast path. Falls back to object
-        tasks over lazy row views when the wave is not cohort-eligible.
-        Returns a ``CohortWave`` or list."""
+        submit) whose rows enter as object tasks over lazy row views.
+        Returns a task list, or the scheduler's batch handle when
+        admission-gated."""
         if n <= 0:
             return []
         return self.submit_batch(DescriptionBatch.from_template(template, n))
@@ -304,7 +298,7 @@ class TaskManager:
         def finished() -> bool:
             if watched is not None:
                 return all(t.done for t in watched)
-            return (all(w.done for w in self._waves)
+            return (all(b.done for b in self._batch_refs)
                     and all(t.done for t in self.tasks.values()))
 
         return self.session.engine.drain(finished, timeout=timeout)

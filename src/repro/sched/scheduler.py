@@ -283,8 +283,7 @@ class CampaignScheduler:
                 release_name(view.index))
             self.views.append(view)
             self._live.append(view)
-            agent.add_done_callback(self._on_task_done,
-                                    cohort_safe=self._cohort_safe)
+            agent.add_done_callback(self._on_task_done)
             if self.admission and self.gang_reserve:
                 # arm backend-level gang reservations: the launch servers
                 # perform the authoritative drain for gangs this scheduler
@@ -298,17 +297,6 @@ class CampaignScheduler:
         """Terminal-state listener across every registered pilot (the
         surface campaigns bind to)."""
         self._done_callbacks.append(cb)
-
-    def _cohort_safe(self) -> bool:
-        """Probe for the agent's cohort fast path: skipping per-task
-        ``_on_task_done`` calls is semantics-preserving exactly when this
-        scheduler holds no per-task state a completion would advance — no
-        admission accounting, no allocations to credit, no dependency
-        waiters, no held entries, no campaign listeners."""
-        return (not self.admission and not self._released
-                and not self._dep_wait and not self._entry_by_uid
-                and not self._gangs and not len(self.policy)
-                and not self._batch_refs and not self._done_callbacks)
 
     # ------------------------------------------------------------- properties
     @property
@@ -332,9 +320,8 @@ class CampaignScheduler:
     # ------------------------------------------------------------------ submit
     def submit(self, descriptions):
         """Submit a description list or a :class:`DescriptionBatch`. Lists
-        return ``List[Task]``; batches return whatever the native batch
-        path produces — a ``CohortWave`` / task list in passthrough, a
-        :class:`_BatchRef` when admission-gated."""
+        return ``List[Task]``; batches return a task list in passthrough
+        and a :class:`_BatchRef` when admission-gated."""
         if isinstance(descriptions, DescriptionBatch):
             return self._submit_batch(descriptions)
         return self._submit(list(descriptions), origin="", resubmit=False)
@@ -464,18 +451,7 @@ class CampaignScheduler:
             if resubmit:
                 tasks = view.agent.resubmit(ready, origin)
             else:
-                # allow the agent's cohort fast path only when the whole
-                # bulk is dependency-free: a wave has no per-task objects
-                # to splice into the placeholder slots
-                tasks = view.agent.submit(ready,
-                                          cohort=len(ready) == len(out))
-            if not isinstance(tasks, list):
-                # planned CohortWave: columnar, already in flight
-                engine.profiler.record(engine.now(), self.uid,
-                                       TRACE_NAMES["release"],
-                                       {"n": len(tasks),
-                                        "pilot": view.index})
-                return tasks
+                tasks = view.agent.submit(ready)
             it = iter(tasks)
             for i, slot in enumerate(out):
                 if isinstance(slot, TaskDescription):

@@ -1,21 +1,21 @@
 """Golden-equivalence suite for the columnar DescriptionBatch submission
-path (PR 9 tentpole): the same campaign submitted as a
-``DescriptionBatch`` vs a ``List[TaskDescription]`` must produce identical
-``compute_metrics`` (ints exact, floats <=1e-9), identical ``state:*``
-trace event counts, and — under a gated scheduler — the identical
-per-pilot release order, on the flux-only and flux+dragon hybrid configs,
-on both engines. Plus batch round-trips, the scheduler's conservative
-fallback gates, dependency-target visibility into pending batch rows, and
-a property test over random mixed batches (sparse fields, deps,
-priorities) with a seeded fallback when hypothesis is absent."""
+path: the same campaign submitted as a ``DescriptionBatch`` (or a
+``submit_wave`` template wave) vs a ``List[TaskDescription]`` must produce
+identical ``compute_metrics`` (ints exact, floats <=1e-9), identical
+``state:*`` trace event counts, and — under a gated scheduler — the
+identical per-pilot release order, on the flux-only and flux+dragon hybrid
+configs, on both engines. Plus batch round-trips, the scheduler's
+conservative fallback gates, dependency-target visibility into pending
+batch rows, and property tests over random uniform waves and random mixed
+batches (sparse fields, deps, priorities) with seeded fallbacks when
+hypothesis is absent."""
 import random
 
 import pytest
 
 from repro.core import analytics as A
 from repro.core.pilot import PilotDescription
-from repro.core.task import (CohortWave, DescriptionBatch, TaskDescription,
-                             TaskState)
+from repro.core.task import DescriptionBatch, TaskDescription, TaskState
 from repro.runtime import PilotManager, Session, TaskManager
 from repro.sched import CampaignScheduler, FairSharePolicy, PriorityPolicy
 from repro.sched.scheduler import release_name
@@ -49,8 +49,23 @@ def _mixed_descs(n, *, hybrid=False, seed=5, priorities=(0,), tenants=("",),
     return out
 
 
+def _null_descs(n, hybrid=False, cores=1, duration=0.0, seed=None):
+    """Builder of ``n`` uniform descriptions (kinds alternate when
+    ``hybrid``); ``seed`` draws each duration from U(0, 0.2) instead, the
+    same draws on every call."""
+    def build():
+        rng = random.Random(seed) if seed is not None else None
+        out = []
+        for i in range(n):
+            kind = "function" if (hybrid and i % 2) else "executable"
+            dur = rng.uniform(0.0, 0.2) if rng is not None else duration
+            out.append(TaskDescription(kind=kind, cores=cores, duration=dur))
+        return out
+    return build
+
+
 def _run(descs_fn, *, as_batch, hybrid=False, mode="sim", seed=42,
-         sched_fn=None, cohort=True, nodes=32, partitions=4):
+         sched_fn=None, nodes=32, partitions=4, wave=None):
     with Session(mode=mode, seed=seed) as session:
         if hybrid:
             backends = {"flux": {"nodes": nodes // 2,
@@ -60,16 +75,18 @@ def _run(descs_fn, *, as_batch, hybrid=False, mode="sim", seed=42,
         else:
             backends = {"flux": {"partitions": partitions}}
         pilot = PilotManager(session).submit_pilots(
-            PilotDescription(nodes=nodes, backends=backends),
-            cohort=cohort, cohort_min=500)
+            PilotDescription(nodes=nodes, backends=backends))
         sched = sched_fn() if sched_fn is not None else None
         tm = (TaskManager(session, scheduler=sched) if sched is not None
               else TaskManager(session))
         tm.add_pilots(pilot)
-        descs = descs_fn()
-        payload = (DescriptionBatch.from_descriptions(descs) if as_batch
-                   else descs)
-        submitted = tm.submit_tasks(payload)
+        if wave is not None:
+            submitted = tm.submit_wave(*wave)
+        else:
+            descs = descs_fn()
+            payload = (DescriptionBatch.from_descriptions(descs) if as_batch
+                       else descs)
+            submitted = tm.submit_tasks(payload)
         assert tm.wait_tasks(timeout=120)
         agent = pilot.agent
         tasks = agent.all_tasks()
@@ -84,6 +101,9 @@ def _run(descs_fn, *, as_batch, hybrid=False, mode="sim", seed=42,
             "submitted": submitted,
             "metrics": A.compute_metrics(tasks, agent.total_cores),
             "series": A.concurrency_series(tasks),
+            "occupancy": A.occupancy_utilization(tasks, agent.total_cores),
+            "completed": {name: ex.stats["completed"]
+                          for name, ex in agent.backends.items()},
             "trace_counts": {k: v for k, v in
                              prof.counts_by_name().items()
                              if k.startswith("state:")},
@@ -107,14 +127,16 @@ def _assert_equivalent(off, on, exact_floats=True):
             rel = abs(got_v - ref_v) / abs(ref_v)
             assert rel <= 1e-9, f"{fname}: {got_v} vs {ref_v} (rel {rel})"
     assert off["trace_counts"] == on["trace_counts"]
+    assert off["completed"] == on["completed"]
     assert off["n_unfinished"] == on["n_unfinished"] == 0
     if exact_floats:
         assert off["series"] == on["series"]
+        assert off["occupancy"] == on["occupancy"]
         assert off["end"] == on["end"]
 
 
 # --------------------------------------------------------------------------
-# tentpole equivalence: passthrough (cohort-planned and object fallback)
+# passthrough equivalence: list vs batch vs template wave
 # --------------------------------------------------------------------------
 
 def test_batch_golden_flux_sim():
@@ -131,36 +153,50 @@ def test_batch_golden_hybrid_sim():
     _assert_equivalent(off, on)
 
 
-def test_batch_golden_cohort_disabled_object_fallback():
-    # cohort gate off forces the bulk object-ingestion path for batches
-    kw = dict(n=800, seed=7)
-    off = _run(lambda: _mixed_descs(**kw), as_batch=False, cohort=False)
-    on = _run(lambda: _mixed_descs(**kw), as_batch=True, cohort=False)
+def _big(descs_fn, hybrid=False):
+    """List vs batch of one description set on the 64-node config."""
+    off = _run(descs_fn, as_batch=False, hybrid=hybrid, nodes=64,
+               partitions=8)
+    on = _run(descs_fn, as_batch=True, hybrid=hybrid, nodes=64,
+              partitions=8)
+    assert isinstance(on["submitted"], list)
     _assert_equivalent(off, on)
 
 
-def test_batch_uniform_wave_plans_cohort():
-    template = TaskDescription(cores=1, duration=0.0)
-    on = _run(lambda: [TaskDescription(uid=f"w.{i}", cores=1, duration=0.0)
-                       for i in range(1200)], as_batch=True)
-    wave = on["submitted"]
-    assert isinstance(wave, CohortWave)
-    assert len(wave) == 1200
-    assert template is not None
+def test_batch_golden_flux_null():
+    _big(_null_descs(2500))
+
+
+def test_batch_golden_hybrid_null():
+    _big(_null_descs(2500, hybrid=True), hybrid=True)
+
+
+def test_batch_golden_uniform_duration_pool_binding():
+    # nonzero durations make allocations outlive launches
+    _big(_null_descs(2000, cores=8, duration=0.5))
+
+
+def test_batch_golden_random_durations_hybrid():
+    _big(_null_descs(2000, hybrid=True, cores=2, seed=7), hybrid=True)
+
+
+def test_wave_api_matches_descs():
+    off = _run(_null_descs(2500), as_batch=False, nodes=64, partitions=8)
+    on = _run(None, as_batch=True, nodes=64, partitions=8,
+              wave=(TaskDescription(cores=1, duration=0.0), 2500))
+    assert isinstance(on["submitted"], list)
+    assert len(on["submitted"]) == 2500
+    _assert_equivalent(off, on)
 
 
 def test_batch_capacity_bound_wave_matches_object():
     # nonzero durations on a small cluster: the pool binds (8x more tasks
-    # than cores), so the cohort finish-heap model must pace launches on
-    # real finishes. Regression for the candidate scan handing a popped
-    # (still-running) slot to a launch on another instance — the wave
-    # oversubscribed cores whenever a group spanned several instances.
+    # than cores), so launches are paced by real finishes
     def descs():
         return [TaskDescription(uid=f"cap.{i}", cores=1, duration=180.0)
                 for i in range(896)]
     off = _run(descs, as_batch=False, nodes=4, partitions=2)
     on = _run(descs, as_batch=True, nodes=4, partitions=2)
-    assert isinstance(on["submitted"], CohortWave)
     _assert_equivalent(off, on)
     assert on["metrics"].concurrency_peak <= 4 * 56
 
@@ -348,6 +384,44 @@ try:
     _HAVE_HYPOTHESIS = True
 except ImportError:
     _HAVE_HYPOTHESIS = False
+
+
+def _uniform_case(n, cores, duration, hybrid):
+    """A uniform list against the same tasks as a batch (hybrid: kinds
+    alternate) or as one template wave (flux only)."""
+    descs = _null_descs(n, hybrid=hybrid, cores=cores, duration=duration)
+    off = _run(descs, as_batch=False, hybrid=hybrid, nodes=64, partitions=8)
+    wave = (None if hybrid
+            else (TaskDescription(cores=cores, duration=duration), n))
+    on = _run(descs, as_batch=True, hybrid=hybrid, nodes=64, partitions=8,
+              wave=wave)
+    _assert_equivalent(off, on)
+
+
+if _HAVE_HYPOTHESIS:
+    @settings(max_examples=10, deadline=None)
+    @given(n=st.integers(min_value=500, max_value=1500),
+           cores=st.integers(min_value=1, max_value=16),
+           duration=st.floats(min_value=0.0, max_value=1.0,
+                              allow_nan=False, allow_infinity=False),
+           hybrid=st.booleans())
+    def test_property_uniform_waves(n, cores, duration, hybrid):
+        _uniform_case(n, cores, duration, hybrid)
+else:
+    @pytest.mark.skip(reason="hypothesis not installed")
+    def test_property_uniform_waves():
+        pass
+
+
+def test_property_uniform_waves_seeds_fallback():
+    """Seeded stand-in for the uniform-wave sweep (always runs): random
+    wave shapes across both configs."""
+    rng = random.Random(11)
+    for _ in range(4):
+        _uniform_case(n=rng.randint(500, 1200),
+                      cores=rng.choice((1, 2, 8, 16)),
+                      duration=rng.choice((0.0, rng.uniform(0.0, 1.0))),
+                      hybrid=rng.random() < 0.5)
 
 
 if _HAVE_HYPOTHESIS:

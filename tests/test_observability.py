@@ -1,7 +1,7 @@
 """Observability layer tests: the vectorized profiler name index against a
 reference loop (golden), lifecycle decomposition telescoping + reconciliation
-with compute_metrics on both engines and both task paths (object vs cohort
-wave), reconstructed timeseries invariants, Chrome trace export round-trip
+with compute_metrics on both engines and both submission forms (description
+list vs DescriptionBatch), reconstructed timeseries invariants, Chrome trace export round-trip
 (schema + per-track monotonicity + non-silent slice cap), the gauge-only
 Watcher's drain guarantee, and the unified RunReport payload/render/CLI surface."""
 import json
@@ -12,7 +12,8 @@ import pytest
 from repro.core import analytics as A
 from repro.core.events import _NAME_MASK, Profiler
 from repro.core.pilot import PilotDescription
-from repro.core.task import STATE_EVENTS, TaskDescription, TaskState
+from repro.core.task import (STATE_EVENTS, DescriptionBatch,
+                             TaskDescription, TaskState)
 from repro.observability import (PHASES, RunReport, Watcher,
                                  backend_inflight, chrome_trace,
                                  export_chrome_trace, inflight,
@@ -29,15 +30,14 @@ REL = 1e-9
 # campaign harness
 # --------------------------------------------------------------------------
 
-def _run(n=400, duration=0.25, cohort=False, hybrid=False, mode="sim",
+def _run(n=400, duration=0.25, as_batch=False, hybrid=False, mode="sim",
          seed=7):
     backends = ({"flux": {"nodes": 8, "partitions": 2},
                  "dragon": {"nodes": 8, "partitions": 2}} if hybrid
                 else {"flux": {"partitions": 4}})
     with Session(mode=mode, seed=seed) as session:
         pilot = PilotManager(session).submit_pilots(
-            PilotDescription(nodes=16, backends=backends),
-            cohort=cohort, cohort_min=100)
+            PilotDescription(nodes=16, backends=backends))
         tm = TaskManager(session)
         tm.add_pilots(pilot)
         if mode == "real":
@@ -51,7 +51,8 @@ def _run(n=400, duration=0.25, cohort=False, hybrid=False, mode="sim",
         else:
             descs = [TaskDescription(cores=1, duration=duration)
                      for _ in range(n)]
-        tm.submit_tasks(descs)
+        tm.submit_tasks(DescriptionBatch.from_descriptions(descs)
+                        if as_batch else descs)
         assert tm.wait_tasks(timeout=120)
         agent = pilot.agent
         return (agent.all_tasks(), agent.total_cores, session.profiler,
@@ -165,7 +166,7 @@ def test_numpy_accessors_match_lists_and_do_not_pin_buffers():
 # --------------------------------------------------------------------------
 
 def test_lifecycle_telescopes_sim_object_path():
-    tasks, cores, prof, mode = _run(cohort=False)
+    tasks, cores, prof, mode = _run()
     bd = lifecycle_breakdown(tasks, prof, by="backend")
     assert bd.n_tasks == 400 and bd.n_skipped == 0
     _assert_telescopes(bd, tasks, cores, prof, mode)
@@ -173,7 +174,7 @@ def test_lifecycle_telescopes_sim_object_path():
 
 
 def test_lifecycle_telescopes_hybrid():
-    tasks, cores, prof, mode = _run(hybrid=True, cohort=False)
+    tasks, cores, prof, mode = _run(hybrid=True)
     bd = lifecycle_breakdown(tasks, prof, by="backend")
     assert set(bd.groups) == {"flux", "dragon"}
     _assert_telescopes(bd, tasks, cores, prof, mode)
@@ -186,22 +187,16 @@ def test_lifecycle_telescopes_real_engine():
     _assert_telescopes(bd, tasks, cores, prof, mode)
 
 
-def test_lifecycle_cohort_vs_object_path():
-    """The cohort wave's columnar decomposition must match the object
-    path's task-by-task one — same campaign, same seed, gate flipped."""
-    t_obj, c_obj, p_obj, _ = _run(cohort=False, seed=11)
-    t_coh, c_coh, p_coh, _ = _run(cohort=True, seed=11)
-    from repro.core.task import TaskCohort
-    assert any(isinstance(t, TaskCohort) for t in t_coh), \
-        "cohort gate did not engage — test would compare object vs object"
-    bd_obj = lifecycle_breakdown(t_obj, p_obj, by="backend")
-    bd_coh = lifecycle_breakdown(t_coh, p_coh, by="backend")
-    assert bd_coh.n_tasks == bd_obj.n_tasks
-    for p in PHASES:
-        a, b = bd_obj.total.phases[p], bd_coh.total.phases[p]
-        assert b.sum == pytest.approx(a.sum, rel=REL, abs=1e-9), p
-        assert b.p99 == pytest.approx(a.p99, rel=REL, abs=1e-9), p
-    _assert_telescopes(bd_coh, t_coh, c_coh, p_coh)
+def test_lifecycle_batch_vs_list_path():
+    """A DescriptionBatch's bulk-stamped rows must decompose exactly like
+    the same campaign submitted as a description list."""
+    t_list, _, p_list, _ = _run(seed=11)
+    t_bat, c_bat, p_bat, _ = _run(as_batch=True, seed=11)
+    bd_list = lifecycle_breakdown(t_list, p_list, by="backend")
+    bd_bat = lifecycle_breakdown(t_bat, p_bat, by="backend")
+    assert bd_bat.n_tasks == bd_list.n_tasks == 400
+    assert bd_bat.as_dict() == bd_list.as_dict()
+    _assert_telescopes(bd_bat, t_bat, c_bat, p_bat)
 
 
 def test_lifecycle_grouping_and_skips():

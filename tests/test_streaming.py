@@ -1,6 +1,6 @@
 """Streaming telemetry tests: the TraceCursor exactly-once/O(delta)
 contract, the golden guarantee that streamed aggregates equal the post-hoc
-reconstruction at drain (both engines, both task paths), edge-triggered
+reconstruction at drain (both engines, list and batch submission), edge-triggered
 health alerts (stall, service p99 SLO breach under replica kill), the
 watch CLI emit/follow round-trip, the Perfetto instants/service slices,
 and the group-aware cross-run diff."""
@@ -13,7 +13,7 @@ import pytest
 from repro.core.agent import Agent, SimEngine
 from repro.core.events import Profiler
 from repro.core.pilot import PilotDescription
-from repro.core.task import TaskDescription
+from repro.core.task import DescriptionBatch, TaskDescription
 from repro.observability import (PHASES, RunReport, chrome_trace,
                                  lifecycle_breakdown)
 from repro.observability.__main__ import main as obs_main
@@ -68,12 +68,11 @@ def test_cursor_exactly_once_and_o_delta():
 # golden: streamed == post-hoc at drain
 # --------------------------------------------------------------------------
 
-def _watched_run(n=400, duration=0.25, cohort=False, mode="sim", seed=7):
+def _watched_run(n=400, duration=0.25, as_batch=False, mode="sim", seed=7):
     with Session(mode=mode, seed=seed) as session:
         pilot = PilotManager(session).submit_pilots(
             PilotDescription(nodes=16,
-                             backends={"flux": {"partitions": 4}}),
-            cohort=cohort, cohort_min=100)
+                             backends={"flux": {"partitions": 4}}))
         tm = TaskManager(session)
         tm.add_pilots(pilot)
         w = tm.watch(interval=1.0)
@@ -83,7 +82,8 @@ def _watched_run(n=400, duration=0.25, cohort=False, mode="sim", seed=7):
         else:
             descs = [TaskDescription(cores=1, duration=duration)
                      for _ in range(n)]
-        tm.submit_tasks(descs)
+        tm.submit_tasks(DescriptionBatch.from_descriptions(descs)
+                        if as_batch else descs)
         assert tm.wait_tasks(timeout=120)
         w.finalize()
         # session close records a few shutdown rows after this returns, so
@@ -129,10 +129,10 @@ def _assert_golden(w, tasks, cores, prof, levels=True):
         assert sp["max"] == pp["max"]
 
 
-@pytest.mark.parametrize("cohort", [False, True],
-                         ids=["objects", "cohort-wave"])
-def test_streamed_equals_posthoc_sim(cohort):
-    w, tasks, cores, prof = _watched_run(cohort=cohort)
+@pytest.mark.parametrize("as_batch", [False, True],
+                         ids=["objects", "batch"])
+def test_streamed_equals_posthoc_sim(as_batch):
+    w, tasks, cores, prof = _watched_run(as_batch=as_batch)
     assert w.n_ticks > 0
     _assert_golden(w, tasks, cores, prof)
 
@@ -144,8 +144,7 @@ def test_streamed_equals_posthoc_real():
 
 def test_streamed_survives_retries():
     """Walltime kills with checkpoint-banked progress retry to DONE: the
-    killed attempts' FAILED rows disable the aligned fast path, and the
-    fallback join must still match post-hoc exactly (retried lifecycles
+    streamed join must still match post-hoc exactly (retried lifecycles
     use first-wins sched/queued stamps)."""
     eng = SimEngine(seed=3)
     agent = Agent(eng, 8, {"flux": {"partitions": 2}})
@@ -161,7 +160,6 @@ def test_streamed_survives_retries():
     w.finalize()
     assert all(t.state.name == "DONE" for t in tasks)
     assert any(t.retries for t in tasks), "no retry was exercised"
-    assert w._saw_retry          # aligned fast path disabled mid-run
     _assert_golden(w, tasks, agent.total_cores, eng.profiler,
                    levels=False)
 
